@@ -17,20 +17,20 @@ from __future__ import annotations
 
 import json
 import os
-import resource
 import subprocess
 import sys
 import time
 from typing import Dict, List, Optional
 
+from benchmarks import mega_sweep
 from benchmarks.common import row
 from repro.eval import run_matrix
 from repro.eval.fabric import executor as _fabric_executor
 from repro.eval.fabric import jax_backend as _jax_backend
-from repro.eval.fabric import xla_cache
+from repro.eval.fabric import jaxenv
 from repro.eval.scenarios import default_matrix, full_matrix, smoke_matrix
 
-#: repo root (the subprocess legs run ``python -m benchmarks.mega_sweep``
+#: repo root (the CPU scaling leg runs ``python -m benchmarks.mega_sweep``
 #: from here so ``src`` + the benchmarks package resolve)
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -43,10 +43,6 @@ _JAX_TARGET_RATIO = 2.0
 #: many seconds on the full grid (canonical bucketing keeps the trace
 #: count flat; the persistent XLA cache turns recompiles into disk reads)
 _COLD_BUDGET_S = 20.0
-
-
-def _peak_rss_mb() -> float:
-    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
 
 
 def _provenance() -> Dict:
@@ -66,39 +62,29 @@ def _provenance() -> Dict:
     }
 
 
-def _mega_subprocess(
-    n_candidates: int, devices: int = 0, timeout: float = 1800.0,
-    matrix: Optional[str] = None,
-) -> Optional[Dict]:
-    """One ``benchmarks.mega_sweep`` run in a fresh interpreter: clean
-    per-run peak RSS (``ru_maxrss`` is process-lifetime, so in-process
-    numbers inherit earlier legs' peaks) and, for ``devices > 0``, a
-    simulated multi-device topology (the XLA host device count is fixed
-    at jax import). Returns the parsed JSON row, or None on failure
-    (recorded as an absent leg, never a bench crash)."""
-    cmd = [
-        sys.executable, "-m", "benchmarks.mega_sweep",
-        "--candidates", str(n_candidates),
-    ]
-    if devices:
-        cmd += ["--devices", str(devices)]
-    if matrix:
-        cmd += ["--matrix", matrix]
+def _scaling_subprocess(n_candidates: int, devices: int) -> Dict:
+    """The multi-device scaling row: ``benchmarks.mega_sweep`` on
+    ``devices`` simulated CPU host devices. That device count is fixed
+    at jax import, so it needs its own interpreter; the child is held to
+    the CPU and never asks for an accelerator. A failed child fails the
+    bench."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (
             os.path.join(_ROOT, "src"), _ROOT,
             env.get("PYTHONPATH", ""),
         ) if p
     )
-    try:
-        proc = subprocess.run(
-            cmd, cwd=_ROOT, env=env, capture_output=True, text=True,
-            timeout=timeout,
-        )
-        return json.loads(proc.stdout.strip().splitlines()[-1])
-    except Exception:
-        return None
+    proc = subprocess.run(
+        [
+            sys.executable, "-m", "benchmarks.mega_sweep",
+            "--candidates", str(n_candidates), "--devices", str(devices),
+        ],
+        cwd=_ROOT, env=env, capture_output=True, text=True, timeout=1800,
+        check=True,
+    )
+    return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 def _time_backend(scenarios, backend: str, repeat: int = 2):
@@ -188,58 +174,52 @@ def _time_tuner(scenarios, grid_name: str, claims, heuristics) -> Dict:
         for e in sha.entries
     )
     # the mega-sweep leg: the full candidate plane (>= 10k rows) on the
-    # jax driver through the pipelined executor, run in a *fresh
-    # subprocess* so peak RSS is the sweep's own (not inherited from the
-    # grid legs above), plus a 4-simulated-device scaling row from a
-    # second subprocess (the XLA host device count is import-time)
+    # jax driver through the pipelined executor, in this process (peak
+    # RSS is the leg's own: mega_sweep resets the high-water mark), plus
+    # — on the CPU only — a 4-simulated-device scaling row from a CPU
+    # child process (the XLA host device count is import-time)
     mega = None
     if grid_name == "full":
-        mega = _mega_subprocess(n_candidates)
-        if mega is not None:
-            scaling = _mega_subprocess(n_candidates, devices=4)
-            if scaling is not None:
-                mega["scaling_4dev"] = {
-                    k: scaling[k]
-                    for k in (
-                        "wall_s", "rows_per_s", "peak_rss_mb",
-                        "device_count", "executor", "donation",
-                    )
-                }
-            jax_wall = mega["wall_s"]
-            rss_peak = mega["peak_rss_mb"]
-            claims.check(
-                "16k+-row candidate plane sweeps on jax via columnar "
-                "plan ingest: peak RSS <= 1.6 GB and wall >= 1.5x "
-                "faster than the NumPy oracle (warm cache)",
-                mega["evals"] >= 10_000
-                and rss_peak <= 1638.0
-                and jax_wall * 1.5 <= oracle_wall,
-                f"{mega['evals']} rows in {jax_wall:.1f}s "
-                f"(numpy {oracle_wall:.1f}s), peak RSS {rss_peak:.0f} MB, "
-                f"{mega['compiled_programs']} compiled programs, "
-                f"executor={mega['executor']} donation={mega['donation']}",
-            )
-            if scaling is not None:
-                # multi-core hosts must scale positive across devices;
-                # on a single core the executor caps the virtual-device
-                # fanout, so the row degenerates to the 1-device path
-                # and only gross collapse (the pre-cap 0.44x) is wrong
-                cores = os.cpu_count() or 1
-                floor = 1.0 if cores >= 2 else 0.9
-                claims.check(
-                    "4-simulated-device sweep holds the 1-device rate "
-                    "(>= 1.0x on multi-core hosts; >= 0.9x on one core "
-                    "where virtual devices share it)",
-                    scaling["rows_per_s"] >= floor * mega["rows_per_s"],
-                    f"{scaling['rows_per_s']:.0f} vs "
-                    f"{mega['rows_per_s']:.0f} rows/s "
-                    f"({cores} cores)",
+        mega = mega_sweep.sweep("full", n_candidates)
+        scaling = None
+        if mega["platform"] == "cpu":
+            scaling = _scaling_subprocess(n_candidates, devices=4)
+            mega["scaling_4dev"] = {
+                k: scaling[k]
+                for k in (
+                    "wall_s", "rows_per_s", "peak_rss_mb",
+                    "device_count", "executor", "donation",
                 )
-        else:
+            }
+        jax_wall = mega["wall_s"]
+        rss_peak = mega["peak_rss_mb"]
+        claims.check(
+            "16k+-row candidate plane sweeps on jax via columnar "
+            "plan ingest: peak RSS <= 1.6 GB and wall >= 1.5x "
+            "faster than the NumPy oracle (warm cache)",
+            mega["evals"] >= 10_000
+            and rss_peak <= 1638.0
+            and jax_wall * 1.5 <= oracle_wall,
+            f"{mega['evals']} rows in {jax_wall:.1f}s "
+            f"(numpy {oracle_wall:.1f}s), peak RSS {rss_peak:.0f} MB, "
+            f"{mega['compiled_programs']} compiled programs, "
+            f"executor={mega['executor']} donation={mega['donation']}",
+        )
+        if scaling is not None:
+            # multi-core hosts must scale positive across devices;
+            # on a single core the executor caps the virtual-device
+            # fanout, so the row degenerates to the 1-device path
+            # and only gross collapse (the pre-cap 0.44x) is wrong
+            cores = os.cpu_count() or 1
+            floor = 1.0 if cores >= 2 else 0.9
             claims.check(
-                "mega-sweep subprocess leg completed",
-                False,
-                "benchmarks.mega_sweep subprocess failed; see stderr",
+                "4-simulated-device sweep holds the 1-device rate "
+                "(>= 1.0x on multi-core hosts; >= 0.9x on one core "
+                "where virtual devices share it)",
+                scaling["rows_per_s"] >= floor * mega["rows_per_s"],
+                f"{scaling['rows_per_s']:.0f} vs "
+                f"{mega['rows_per_s']:.0f} rows/s "
+                f"({cores} cores)",
             )
 
     out = {
@@ -348,7 +328,7 @@ def run(claims) -> List[Dict]:
             f"cold {backends['jax']['wall_s_cold']:.1f}s - steady "
             f"{backends['jax']['wall_s']:.1f}s = {cold_tax:.1f}s "
             f"(persistent cache "
-            f"{'on' if xla_cache.enabled() else 'off'})",
+            f"{'on' if jaxenv.cache_dir() else 'off'})",
         )
         rps = backends["jax"].get("host_rounds_per_scenario", 1.0)
         replays = backends["jax"].get("post_row_replays_per_run", 1)
@@ -375,34 +355,27 @@ def run(claims) -> List[Dict]:
     )
 
     # the multi-tenant fleet leg: coupled tenant_matrix throughput on
-    # jax (rows/s + the sweep's own peak RSS via a fresh subprocess),
+    # jax (rows/s + the leg's own peak RSS, measured in this process),
     # the coupled-vs-uncoupled overhead, and the contention report
     # (greedy per-tenant heuristics vs the contended static oracle) —
     # the full grid runs the full 36-group fleet, smaller grids the
     # 6-group smoke fleet
     fleet_matrix = "tenant" if grid_name == "full" else "tenant-smoke"
-    fleet = _mega_subprocess(8, matrix=fleet_matrix)
-    if fleet is not None:
-        contention = fleet.get("contention", {})
-        claims.check(
-            "multi-tenant fleet: coupled sweep holds the RSS gate and "
-            "greedy per-tenant tuning does not collapse under "
-            "contention (median regret >= 0.75 vs the contended "
-            "static oracle)",
-            fleet["peak_rss_mb"] <= 1638.0
-            and contention.get("regret_median", 0.0) >= 0.75,
-            f"{fleet['evals']} tenants at {fleet['rows_per_s']:.0f} "
-            f"rows/s, peak RSS {fleet['peak_rss_mb']:.0f} MB, "
-            f"coupled overhead {fleet.get('coupled_overhead')}x, "
-            f"median regret {contention.get('regret_median', 0):.3f} "
-            f"({contention.get('groups', 0)} groups)",
-        )
-    else:
-        claims.check(
-            "multi-tenant fleet subprocess leg completed",
-            False,
-            "benchmarks.mega_sweep --matrix tenant subprocess failed",
-        )
+    fleet = mega_sweep.sweep(fleet_matrix, 8)
+    contention = fleet.get("contention", {})
+    claims.check(
+        "multi-tenant fleet: coupled sweep holds the RSS gate and "
+        "greedy per-tenant tuning does not collapse under "
+        "contention (median regret >= 0.75 vs the contended "
+        "static oracle)",
+        fleet["peak_rss_mb"] <= 1638.0
+        and contention.get("regret_median", 0.0) >= 0.75,
+        f"{fleet['evals']} tenants at {fleet['rows_per_s']:.0f} "
+        f"rows/s, peak RSS {fleet['peak_rss_mb']:.0f} MB, "
+        f"coupled overhead {fleet.get('coupled_overhead')}x, "
+        f"median regret {contention.get('regret_median', 0):.3f} "
+        f"({contention.get('groups', 0)} groups)",
+    )
 
     LAST_SNAPSHOT = {
         "bench": "eval_matrix",
@@ -411,13 +384,13 @@ def run(claims) -> List[Dict]:
         # execution provenance: jax/platform/devices + executor mode and
         # donation state the backends ran under
         "execution": _provenance(),
-        # cold numbers only mean anything relative to this: with the
-        # persistent cache armed (REPRO_XLA_CACHE) "cold" is a fresh
-        # process reading compiled executables off disk; without it,
-        # cold pays real XLA compiles
+        # cold numbers only mean anything relative to this: with a
+        # populated persistent cache "cold" is a fresh process reading
+        # compiled executables off disk; without it, cold pays real
+        # XLA compiles
         "xla_cache": {
-            "enabled": xla_cache.enabled(),
-            "dir": xla_cache.cache_dir() if xla_cache.enabled() else None,
+            "enabled": jaxenv.cache_dir() is not None,
+            "dir": jaxenv.cache_dir(),
         },
         "backends": backends,
         "tune": tune_snapshot,

@@ -143,47 +143,18 @@ def use_sharding(
 
 
 def mark_varying(x, axes):
-    """Mark ``x`` varying over manual ``axes``, across jax generations.
-
-    Newer jax tracks varying-manual-axes types and exposes ``pcast`` (or the
-    earlier ``pvary``); 0.4.x shard_map has no such type system, so there the
-    annotation is a no-op and values are already treated as device-varying.
-    """
+    """Mark ``x`` varying over manual ``axes`` (jax's varying-manual-axes
+    type)."""
     axes = (axes,) if isinstance(axes, str) else tuple(axes)
-    if hasattr(jax.lax, "pcast"):
-        try:
-            return jax.lax.pcast(x, axes, to="varying")
-        except TypeError:
-            return jax.lax.pcast(x, to="varying", axes=axes)
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axes)
-    return x
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, manual_axes):
-    """shard_map manual over ``manual_axes`` only, across jax generations.
-
-    Newer jax spells this ``jax.shard_map(..., axis_names=manual)``. 0.4.x
-    has partial-manual (``auto=rest``) but its XLA CHECK-fails on real model
-    bodies (hlo_sharding_util IsManualSubgroup), so there we fall back to a
-    FULLY manual region: non-manual axes replicate compute instead of
-    sharding it. Collectives over ``manual_axes`` lower identically, so
-    numerics — and therefore the scheduling/compression semantics under
-    test — are unchanged; only legacy-jax step cost differs.
-    ``check_rep=False`` because the legacy replication checker predates
-    explicitly-scheduled per-bucket collectives.
-    """
-    manual = frozenset(manual_axes)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=set(manual),
-        )
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    return _legacy_shard_map(
+    """shard_map manual over ``manual_axes`` only (the other mesh axes
+    stay automatic)."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        axis_names=set(manual_axes),
     )
 
 

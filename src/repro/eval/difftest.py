@@ -181,6 +181,10 @@ def main(argv: Optional[List[str]] = None) -> int:
             "--expect-zero-replays checks the jax backend's SYNC_STATS; "
             "run with --backend jax or --backend all"
         )
+    if "jax" in backends:
+        from .fabric.jaxenv import arm_compile_cache
+
+        arm_compile_cache()
     if args.expect_zero_replays:
         from .fabric import jax_backend
 

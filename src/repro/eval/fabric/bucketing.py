@@ -29,8 +29,9 @@ planes, and the fuzz harness:
   * Q: the flat file-size buffer, zero-padded at device upload to the
     quarter-step ladder of :func:`qsizes_pad`.
 
-With the persistent XLA cache (``REPRO_XLA_CACHE``) the surviving
-handful of signatures compiles once per machine, not once per process.
+With the persistent compilation cache (armed by the entry points, see
+:mod:`repro.eval.fabric.jaxenv`) the surviving handful of signatures
+compiles once per cache directory, not once per process.
 """
 from __future__ import annotations
 

@@ -243,6 +243,14 @@ class FabricSimulation:
                 f"unknown fused_step {fused!r}; options: none, pallas"
             )
         self.fused_step = fused
+        for option, value in (
+            ("REPRO_FABRIC_WATERFILL", impl),
+            ("REPRO_FABRIC_FUSED_STEP", fused),
+        ):
+            if value == "pallas":
+                from .kernels.waterfill_pallas import require_f64_kernels
+
+                require_f64_kernels(option)
         #: set by the columnar path only: (open_n, visit_rank) initial
         #: channel layout, consumed once by :meth:`_start_plan`
         self._plan_open = None
@@ -1064,9 +1072,9 @@ class FabricSimulation:
                 self.tl_t, self.tl_rate, self.tl_len, self.tl_stride,
                 self.tl_seen, self.tl_last_t, self.tl_last_rate,
             ) = kernels.timeline_push(
-                self.ops, rec, self.t, rates.sum(axis=1), self.tl_t,
-                self.tl_rate, self.tl_len, self.tl_stride, self.tl_seen,
-                self.tl_last_t, self.tl_last_rate,
+                self.ops, rec, self.t, kernels.row_total(self.ops, rates),
+                self.tl_t, self.tl_rate, self.tl_len, self.tl_stride,
+                self.tl_seen, self.tl_last_t, self.tl_last_rate,
             )
 
         dt = kernels.event_horizon(

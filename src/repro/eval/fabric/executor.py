@@ -7,8 +7,9 @@ host sat idle while the device ran and vice versa. This module replaces
 that loop with a small pipeline:
 
   * one **prep thread** walks the chunks in order, builds each chunk's
-    Simulations and driver, submits its canonical-signature ladder to a
-    background AOT warm thread (jax), and hands the ready driver to a
+    Simulations and driver, submits its canonical-signature ladder to
+    its device's background AOT warm thread (jax), and hands the ready
+    driver to a
     bounded per-device queue — explicit double-buffered staging: while
     device ``d`` computes chunk ``j``, chunk ``j + n_devices`` is already
     built and waiting in ``d``'s queue, and the chunk after that is being
@@ -40,6 +41,7 @@ serial loop's fail-fast semantics.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import queue
 import threading
@@ -67,10 +69,29 @@ def executor_mode(override: Optional[str] = None) -> str:
     return mode
 
 
+#: device list set by :func:`pinned_devices` (None: all of jax.devices())
+_PINNED: Optional[list] = None
+
+
+@contextlib.contextmanager
+def pinned_devices(devices: Sequence):
+    """Shard the executor's sweeps over ``devices`` only, for the scope
+    of the ``with`` block (process-wide). One device pins a run to one
+    chip of a multi-chip host; the multi-chip comparison runs the same
+    plane under ``jax.devices()[:1]`` and ``[:4]``."""
+    global _PINNED
+    saved, _PINNED = _PINNED, list(devices)
+    try:
+        yield
+    finally:
+        _PINNED = saved
+
+
 def backend_devices(cls) -> list:
-    """The device list the executor shards over: ``jax.devices()`` for
-    drivers that support placement, else a single anonymous slot (the
-    NumPy driver still gets prep/compute overlap from the pipeline).
+    """The device list the executor shards over: ``jax.devices()`` (or
+    the :func:`pinned_devices` list) for drivers that support placement,
+    else a single anonymous slot (the NumPy driver still gets prep/
+    compute overlap from the pipeline).
 
     XLA *host* devices are virtual — N forced CPU devices timeslice the
     same physical cores — so on the cpu platform the list is capped at
@@ -81,7 +102,7 @@ def backend_devices(cls) -> list:
     if getattr(cls, "supports_device_placement", False):
         import jax
 
-        devices = list(jax.devices())
+        devices = list(jax.devices() if _PINNED is None else _PINNED)
         if devices and devices[0].platform == "cpu":
             devices = devices[: max(1, os.cpu_count() or 1)]
         return devices
@@ -102,25 +123,24 @@ def _warm_chunk(driver) -> None:
     from . import jax_backend
     from .bucketing import canonical_signature, signature_ladder
 
-    try:
-        sig = canonical_signature(driver)
-    except Exception:
-        return  # custom schedulers may defeat the closed-form bound
     floor = driver.compact_floor()
-    for rung in signature_ladder(sig, floor):
+    for rung in signature_ladder(canonical_signature(driver), floor):
         jax_backend.warm_signature(
             rung, device=driver.device, donate=driver.donate, floor=floor
         )
 
 
 def _warm_loop(warm_q: "queue.Queue", stop: Optional[threading.Event],
-               warm: Optional[Callable] = None) -> None:
+               warm: Optional[Callable] = None,
+               fail: Optional[Callable] = None) -> None:
     """Drain ``warm_q`` (driver -> AOT warm; ``None`` sentinel exits).
 
     Warm work is pure prefetch, so on pipeline failure (``stop`` set)
     pending drivers are discarded instead of compiled — errors surface
     as soon as the workers join, not after a stray multi-second XLA
-    compile of a chunk nobody will run."""
+    compile of a chunk nobody will run. A warm that raises fails the
+    pipeline through ``fail``: the compiler refusing a program is a
+    fault, not a reason to compile it again on the critical path."""
     warm = warm or _warm_chunk
     while True:
         driver = warm_q.get()
@@ -130,8 +150,10 @@ def _warm_loop(warm_q: "queue.Queue", stop: Optional[threading.Event],
             continue  # fail-fast: drop pending warms, keep draining
         try:
             warm(driver)
-        except Exception:
-            pass  # a failed warm only means the jit fallback compiles
+        except Exception as exc:
+            if fail is None:
+                raise
+            fail(exc)
 
 
 def execute_chunks(
@@ -240,7 +262,7 @@ def execute_chunks(
                 with wall_timer("build_wall_s"):
                     driver = make_chunk(parts[j], dev)
                 if placed:
-                    warm_pool_submit(driver)
+                    warm_qs[j % len(devices)].put(driver)
                 put(queues[j % len(devices)], (parts[j], driver))
         except BaseException as exc:  # chunk builds can raise anything
             fail(exc)
@@ -263,13 +285,11 @@ def execute_chunks(
             except BaseException as exc:
                 fail(exc)
 
-    # a single warm thread: AOT compiles happen off the critical path but
-    # still one at a time (XLA compiles are already multi-threaded
-    # internally; stacking them thrashes)
-    warm_q: "queue.Queue" = queue.Queue()
-
-    def warm_pool_submit(driver) -> None:
-        warm_q.put(driver)
+    # one warm thread per device: AOT compiles happen off the critical
+    # path, one at a time per device. A single-device program is
+    # compiled for its device, so an N-device sweep compiles each
+    # signature N times; the per-device threads run those in parallel
+    warm_qs: List["queue.Queue"] = [queue.Queue() for _ in devices]
 
     n_prep = max(1, min(prep_workers or 1, max(1, len(parts))))
     prep_threads = [
@@ -280,13 +300,15 @@ def execute_chunks(
         threading.Thread(target=compute, args=(d,), name=f"fabric-dev{d}")
         for d in range(len(devices))
     ]
-    warm_thread = None
-    if placed:
-        warm_thread = threading.Thread(
-            target=_warm_loop, args=(warm_q, stop), name="fabric-warm",
-            daemon=True,
+    warm_threads = [
+        threading.Thread(
+            target=_warm_loop, args=(warm_qs[d], stop, None, fail),
+            name=f"fabric-warm{d}", daemon=True,
         )
-        warm_thread.start()
+        for d in range(len(devices) if placed else 0)
+    ]
+    for t in warm_threads:
+        t.start()
     for t in prep_threads + compute_threads:
         t.start()
     # sentinels flow only after every prep worker is finished (with one
@@ -297,18 +319,18 @@ def execute_chunks(
         put(q, None)
     for t in compute_threads:
         t.join()
-    if warm_thread is not None:
-        # leftover warm work is pure prefetch — drop it, then join: an
-        # abandoned thread still inside an XLA compile when the
-        # interpreter exits aborts the whole process (std::terminate),
-        # and the join waits out at most the one in-flight compile
+    # leftover warm work is pure prefetch — drop it, then join: an
+    # abandoned thread still inside an XLA compile when the interpreter
+    # exits aborts the whole process (std::terminate), and the join waits
+    # out at most the one in-flight compile per thread
+    for warm_q, t in zip(warm_qs, warm_threads):
         try:
             while True:
                 warm_q.get_nowait()
         except queue.Empty:
             pass
         warm_q.put(None)
-        warm_thread.join()
+        t.join()
     if errors:
         raise errors[0]
     return results

@@ -37,8 +37,9 @@ sequence as the synchronized NumPy sweeps; ``eval.difftest`` holds all
 backends to the event simulator within the 2% bar, and ``SYNC_STATS``
 proves the zero-replay property on every run.
 
-Numerics run in float64 via the scoped ``jax.experimental.enable_x64``
-context (never the global flag: the rest of the repo traces in f32).
+Numerics run in float64 via the scoped :func:`jaxenv.x64` context
+(``jax.enable_x64``, never the global flag: the rest of the repo traces
+in f32).
 
 Execution-loop structure (the overlap-pipelined executor rides on it):
 
@@ -63,7 +64,6 @@ Execution-loop structure (the overlap-pipelined executor rides on it):
 """
 from __future__ import annotations
 
-import contextlib
 import os
 import threading
 import time
@@ -86,6 +86,7 @@ from .driver import (
     KIND_SC,
     FabricSimulation,
 )
+from .jaxenv import cache_dir, x64
 from .shim import jax_ops
 
 _ERR_NONE, _ERR_MAXTIME, _ERR_STRANDED = 0, 1, 2
@@ -122,31 +123,12 @@ from .stats import (  # noqa: E402,F401  (re-exported API)
 )
 
 
-def _persistent_cache_active() -> bool:
-    try:
-        return bool(jax.config.jax_compilation_cache_dir)
-    except Exception:
-        return False
-
-
 def donation_enabled(default: Optional[bool] = None) -> bool:
     """Resolve the buffer-donation toggle: ``REPRO_FABRIC_DONATE`` wins,
     else ``default`` (a driver kwarg), else on exactly when the async
     executor is active — ``REPRO_FABRIC_EXECUTOR=serial`` preserves the
-    undonated pre-executor execution path byte for byte.
-
-    A configured persistent compilation cache (``REPRO_XLA_CACHE`` /
-    ``jax_compilation_cache_dir``) no longer blanket-disables donation.
-    The underlying hazard — on jax 0.4.x CPU, donated executables of
-    this program do not survive the cache's serialize/deserialize round
-    trip, and a program read back from disk aliases stale buffers and
-    produces nondeterministic garbage — only bites programs that
-    *round-trip the cache*. Freshly-compiled donated programs in the
-    same process are correct, so every donated compile now runs inside
-    :func:`_suppress_persistent_cache` (donated executables are never
-    serialized, hence never read back); only a signature whose donated
-    compile failed falls back to the undonated cache-served program
-    (see :meth:`JaxFabricSimulation._device_call`)."""
+    undonated pre-executor execution path byte for byte. Donated programs
+    go through the persistent compilation cache like any other."""
     env = os.environ.get("REPRO_FABRIC_DONATE")
     if env is not None:
         return env.strip().lower() not in ("0", "false", "off", "no", "")
@@ -155,48 +137,6 @@ def donation_enabled(default: Optional[bool] = None) -> bool:
     from .executor import executor_mode
 
     return executor_mode() == "async"
-
-
-_SUPPRESS_LOCK = threading.Lock()
-_suppress_depth = 0
-_suppressed_cache_dir: Optional[str] = None
-
-
-@contextlib.contextmanager
-def _suppress_persistent_cache():
-    """Scoped (refcounted, process-wide) removal of the persistent
-    compilation cache directory.
-
-    Donated executables of the device loop must never be serialized to —
-    or deserialized from — the persistent cache (the jax 0.4.x CPU
-    aliasing bug above), so every donated compile runs inside this
-    window. The config flag is process-global, hence the refcount: nested
-    or concurrent donated compiles share one save/restore, and the worst
-    case for an unrelated concurrent compile is one missed cache write,
-    never a wrong program."""
-    global _suppress_depth, _suppressed_cache_dir
-    with _SUPPRESS_LOCK:
-        if _suppress_depth == 0:
-            saved = None
-            try:
-                saved = jax.config.jax_compilation_cache_dir
-            except Exception:
-                saved = None
-            _suppressed_cache_dir = saved
-            if saved:
-                jax.config.update("jax_compilation_cache_dir", None)
-        _suppress_depth += 1
-    try:
-        yield
-    finally:
-        with _SUPPRESS_LOCK:
-            _suppress_depth -= 1
-            if _suppress_depth == 0:
-                if _suppressed_cache_dir:
-                    jax.config.update(
-                        "jax_compilation_cache_dir", _suppressed_cache_dir
-                    )
-                _suppressed_cache_dir = None
 
 
 #: state arrays the device sweep may mutate (host <-> device sync set)
@@ -322,7 +262,8 @@ def _phase_advance(
             tl["tl_t"], tl["tl_rate"], tl["tl_len"], tl["tl_stride"],
             tl["tl_seen"], tl["tl_last_t"], tl["tl_last_rate"],
         ) = kernels.timeline_push(
-            ops, alive & row["record_timeline"], row["t"], xp.sum(rates),
+            ops, alive & row["record_timeline"], row["t"],
+            kernels.row_total(ops, rates),
             row["tl_t"], row["tl_rate"], row["tl_len"], row["tl_stride"],
             row["tl_seen"], row["tl_last_t"], row["tl_last_rate"],
         )
@@ -966,8 +907,8 @@ def signature_shapes(
 
 
 _AOT_LOCK = threading.Lock()
-#: ``(sig, device, donate) -> jax.stages.Compiled`` (``None`` records a
-#: failed warm so the jit fallback handles that signature quietly)
+#: ``(sig, device, donate, floor) -> jax.stages.Compiled`` (``None``
+#: records a warm that raised; the error reaches the warm's caller)
 _AOT_CACHE: dict = {}
 #: in-flight warms: waiters block on the event instead of re-compiling
 _AOT_PENDING: dict = {}
@@ -986,47 +927,48 @@ _AOT_PENDING: dict = {}
 # Cold and warm paths both compile the *exported* call so they share one
 # HLO identity (and one XLA cache entry) per program.
 #
-# Blobs are keyed on the signature plus a digest of the jax version and
-# every source file in this package — any edit to the traced code (or
-# the constants it closes over) invalidates the whole trace cache.
-# Donated programs are excluded (donation metadata does not survive the
-# export round trip; donated compiles run inside the cache-suppression
-# window instead, so they never reach this cache either).
+# Blobs are keyed on the backend platform, the signature and a digest of
+# the jax version and every source file under this package (controllers/
+# and kernels/ included) — any edit to the traced code (or the constants
+# it closes over) invalidates the whole trace cache. A blob exported for
+# another platform is a miss. Donated programs are excluded (donation
+# metadata does not survive the export round trip); they compile from
+# the jit twin, which the persistent XLA cache serves directly.
 
 _EXPORT_DIGEST: Optional[str] = None
 
 
 def _export_digest() -> str:
     """Digest of everything the device-loop trace can depend on: the jax
-    version plus the bytes of every ``.py`` file in this package."""
+    version plus the bytes of every ``.py`` file under this package."""
     global _EXPORT_DIGEST
     if _EXPORT_DIGEST is None:
         import hashlib
 
         h = hashlib.sha256(jax.__version__.encode())
         pkg = os.path.dirname(os.path.abspath(__file__))
-        for name in sorted(os.listdir(pkg)):
-            if name.endswith(".py"):
-                with open(os.path.join(pkg, name), "rb") as f:
-                    h.update(name.encode())
-                    h.update(f.read())
+        for root, dirs, files in os.walk(pkg):
+            dirs.sort()
+            for name in sorted(files):
+                if name.endswith(".py"):
+                    path = os.path.join(root, name)
+                    with open(path, "rb") as f:
+                        h.update(os.path.relpath(path, pkg).encode())
+                        h.update(f.read())
         _EXPORT_DIGEST = h.hexdigest()[:32]
     return _EXPORT_DIGEST
 
 
 def _export_path(sig, floor: int) -> Optional[str]:
-    """Blob path for one signature, or None when no persistent cache
-    directory is configured (no point caching traces the process can't
-    amortize across runs)."""
-    base = None
-    try:
-        base = jax.config.jax_compilation_cache_dir
-    except Exception:
-        return None
+    """Blob path for one signature on the current backend, or None when
+    no persistent cache directory is configured (no point caching traces
+    the process can't amortize across runs)."""
+    base = cache_dir()
     if not base:
         return None
-    name = "rounds-{}-f{}-{}.stablehlo".format(
-        "x".join(str(int(x)) for x in sig), int(floor), _export_digest()
+    name = "rounds-{}-{}-f{}-{}.stablehlo".format(
+        jax.default_backend(), "x".join(str(int(x)) for x in sig),
+        int(floor), _export_digest(),
     )
     return os.path.join(base, "exports", name)
 
@@ -1040,11 +982,10 @@ def _exported_rounds(sig, shapes, floor: int):
 
     path = _export_path(sig, floor)
     if path is not None and os.path.exists(path):
-        try:
-            with open(path, "rb") as f:
-                return jax_export.deserialize(f.read())
-        except Exception:
-            pass  # stale/corrupt blob: fall through to a fresh trace
+        with open(path, "rb") as f:
+            exp = jax_export.deserialize(f.read())
+        if jax.default_backend() in exp.platforms:
+            return exp
     exp = jax_export.export(_device_rounds)(*shapes, int(floor))
     if path is not None:
         try:
@@ -1053,8 +994,8 @@ def _exported_rounds(sig, shapes, floor: int):
             with open(tmp, "wb") as f:
                 f.write(exp.serialize())
             os.replace(tmp, path)
-        except Exception:
-            pass
+        except OSError:
+            pass  # a read-only cache dir only costs the next process a trace
     return exp
 
 
@@ -1090,12 +1031,10 @@ def warm_signature(
         return False
     compiled = None
     try:
-        from jax.experimental import enable_x64
-
         # x64 is thread-local: the warm thread needs its own context so
         # the traced avals match the runtime's f64 uploads
-        with enable_x64():
-            if not donate and _persistent_cache_active():
+        with x64():
+            if not donate and cache_dir():
                 exp = _exported_rounds(
                     sig, signature_shapes(sig, None), floor
                 )
@@ -1104,21 +1043,11 @@ def warm_signature(
                     .lower(*signature_shapes(sig, device))
                     .compile()
                 )
-            elif donate:
-                # donated executables must never enter the persistent
-                # cache (they don't survive its serialize/deserialize
-                # round trip on jax 0.4.x CPU): compile them inside the
-                # cache-suppression window so only fresh programs exist
-                with _suppress_persistent_cache():
-                    compiled = _device_rounds_donated.lower(
-                        *signature_shapes(sig, device), int(floor)
-                    ).compile()
             else:
-                compiled = _device_rounds.lower(
+                fn = _device_rounds_donated if donate else _device_rounds
+                compiled = fn.lower(
                     *signature_shapes(sig, device), int(floor)
                 ).compile()
-    except Exception:
-        compiled = None  # fall back to plain jit for this signature
     finally:
         with _AOT_LOCK:
             _AOT_CACHE[key] = compiled
@@ -1301,43 +1230,20 @@ class JaxFabricSimulation(FabricSimulation):
         Coupled batches never consult the AOT cache — the executor warms
         *uncoupled* signatures, and a shape-compatible uncoupled
         executable would silently run the wrong physics — they go
-        straight to the coupled jit twins (donated ones inside the
-        cache-suppression window, like every donated compile).
-
-        Donated batches under a persistent compilation cache resolve via
-        a synchronous AOT warm (whose compile runs cache-suppressed), so
-        a donated executable never round-trips the cache; if that warm
-        fails, the batch drops to the undonated cache-served program for
-        the rest of its run instead of risking stale-buffer aliasing.
+        straight to the coupled jit twins.
         """
         floor = self.compact_floor()
         if self.coupled:
-            if self.donate:
-                if _persistent_cache_active():
-                    with _suppress_persistent_cache():
-                        return _device_rounds_coupled_donated(
-                            mut, const, qsizes, self._fab_dev, floor
-                        )
-                return _device_rounds_coupled_donated(
-                    mut, const, qsizes, self._fab_dev, floor
-                )
-            return _device_rounds_coupled(
-                mut, const, qsizes, self._fab_dev, floor
+            fn = (
+                _device_rounds_coupled_donated if self.donate
+                else _device_rounds_coupled
             )
-        sig = self._rounds_signature()
-        exe = _aot_lookup(sig, self.device, self.donate, floor)
+            return fn(mut, const, qsizes, self._fab_dev, floor)
+        exe = _aot_lookup(
+            self._rounds_signature(), self.device, self.donate, floor
+        )
         if exe is not None:
             return exe(mut, const, qsizes)
-        if self.donate and _persistent_cache_active():
-            warm_signature(sig, self.device, True, floor)
-            exe = _aot_lookup(sig, self.device, True, floor)
-            if exe is not None:
-                return exe(mut, const, qsizes)
-            self.donate = False
-            exe = _aot_lookup(sig, self.device, False, floor)
-            if exe is not None:
-                return exe(mut, const, qsizes)
-            return _device_rounds(mut, const, qsizes, floor)
         fn = _device_rounds_donated if self.donate else _device_rounds
         return fn(mut, const, qsizes, floor)
 
@@ -1346,10 +1252,12 @@ class JaxFabricSimulation(FabricSimulation):
             if key == "err":
                 continue
             # np.array (not asarray): device buffers are zero-copy
-            # read-only views, and the host half mutates these in place
-            arr = np.array(state[key][: self.S])
+            # read-only views, and the host half mutates these in place.
+            # Pad rows are dropped on the host: slicing the device array
+            # would compile a slice program per (shape, row count, device)
+            arr = np.array(state[key])[: self.S]
             setattr(self, "_stall" if key == "stall" else key, arr)
-        err = np.asarray(state["err"][: self.S])
+        err = np.asarray(state["err"])[: self.S]
         if err.any():
             s = int(np.flatnonzero(err)[0])
             if err[s] == _ERR_MAXTIME:
@@ -1367,8 +1275,6 @@ class JaxFabricSimulation(FabricSimulation):
     # -------------------------------------------------------------- #
 
     def run(self) -> List[SimResult]:
-        from jax.experimental import enable_x64
-
         all_rt = list(self.rt)
         self.start()
         # pre-size the channel and resume-stack axes from the closed-form
@@ -1380,7 +1286,7 @@ class JaxFabricSimulation(FabricSimulation):
             self._grow()
         while self.P < need_p:
             self._grow_prepend()
-        with enable_x64():
+        with x64():
             self._drive()
         return [self._result(r) for r in all_rt]
 

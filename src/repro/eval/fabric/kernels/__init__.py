@@ -298,6 +298,19 @@ def compact_channels(ops: ArrayOps, trig, chunk_of, busy, dead, rem, cap):
     )
 
 
+def row_total(ops: ArrayOps, x):
+    """Left-to-right sum over the last axis. ``xp.sum`` is pairwise in
+    NumPy and, past a width, tree-shaped in XLA, so the two can differ
+    in the last ulp; the timeline's rate total takes this one fixed
+    order so both drivers record bit-identically."""
+    if x.shape[-1] == 0:
+        return ops.xp.zeros(x.shape[:-1], dtype=x.dtype)
+    total = x[..., 0]
+    for c in range(1, x.shape[-1]):
+        total = total + x[..., c]
+    return total
+
+
 def timeline_push(
     ops: ArrayOps, rec, t, rate, buf_t, buf_r, length, stride, seen,
     last_t, last_r,

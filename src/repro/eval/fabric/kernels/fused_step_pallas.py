@@ -169,7 +169,7 @@ def fused_advance_feed_f64(
     Returns ``(dt, rate_sum, fin_any, busy, dead, rem, moved, qptr,
     queue_bytes)``; inactive rows pass through with ``dt = 0``.
     """
-    from jax.experimental import enable_x64
+    from ..jaxenv import x64
 
     if interpret is None:
         interpret = not supports_compiled_pallas()
@@ -183,7 +183,7 @@ def fused_advance_feed_f64(
         qsizes = np.concatenate(
             [qsizes, np.zeros(q_pad - qsizes.shape[0])]
         )
-    with enable_x64():
+    with x64():
         call = _build_call(S, C, K, int(qsizes.shape[0]), bool(interpret))
         out = call(
             jnp.asarray(np.asarray(act, dtype=bool)),

@@ -37,6 +37,21 @@ def supports_compiled_pallas() -> bool:
     return jax.default_backend() in _COMPILED_BACKENDS
 
 
+def require_f64_kernels(option: str) -> None:
+    """Refuse the float64 Pallas kernels (this water-fill and the fused
+    step) where they cannot compile: TPU's kernel compiler (Mosaic) has
+    no 64-bit element types and rejects them with ``UNIMPLEMENTED``.
+    Called when ``option`` selects them, so the run fails at selection
+    instead of mid-sweep."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        raise ValueError(
+            f"{option}=pallas selects a float64 Pallas kernel, which the "
+            f"{backend} kernel compiler refuses (no 64-bit types); use "
+            "the default closed-form path on this backend"
+        )
+
+
 def _waterfill_kernel(caps_ref, pool_ref, out_ref):
     caps = caps_ref[...]
     pool = pool_ref[...]  # (S, 1)
@@ -94,9 +109,9 @@ def waterfill_pallas_f64(caps, pool):
     """float64 wrapper for the NumPy driver: runs the kernel under the
     scoped x64 context and hands back a NumPy array."""
     import numpy as np
-    from jax.experimental import enable_x64
+    from ..jaxenv import x64
 
-    with enable_x64():
+    with x64():
         out = waterfill_pallas(
             jnp.asarray(np.asarray(caps, dtype=np.float64)),
             jnp.asarray(np.asarray(pool, dtype=np.float64)),

@@ -17,20 +17,12 @@ BACKENDS = ("numpy", "jax")
 
 
 def get_backend(name: str) -> Type[FabricSimulation]:
-    """Resolve a fabric backend name to its driver class.
-
-    Resolving ``jax`` also arms the opt-in persistent XLA compilation
-    cache (``REPRO_XLA_CACHE``) — this is the one chokepoint every jax
-    execution path (runner, difftest, tuner, benchmarks) passes through
-    before compiling anything.
-    """
+    """Resolve a fabric backend name to its driver class."""
     if name in ("numpy", "batch"):
         return FabricSimulation
     if name == "jax":
         from .jax_backend import JaxFabricSimulation
-        from .xla_cache import enable_persistent_cache
 
-        enable_persistent_cache()
         return JaxFabricSimulation
     raise ValueError(
         f"unknown fabric backend {name!r}; options: {BACKENDS}"

@@ -614,6 +614,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     args = ap.parse_args(argv)
 
+    if args.backend == "jax":
+        from .fabric.jaxenv import arm_compile_cache
+
+        arm_compile_cache()
     scenarios = build_matrix(args.matrix)
     if args.verbose:
         from .fabric import stats as fabric_stats

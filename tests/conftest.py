@@ -1,14 +1,1 @@
-"""Test-suite bootstrap.
-
-Offline environments (like the container this repo grows in) don't ship the
-``hypothesis`` distribution. Six test modules are property suites, so instead
-of skipping them we vendor a tiny API-compatible shim under ``tests/_compat``
-and put it on ``sys.path`` *only when the real library is missing* — an
-installed hypothesis always takes precedence. See TESTING.md.
-"""
-import importlib.util
-import os
-import sys
-
-if importlib.util.find_spec("hypothesis") is None:
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "_compat"))
+"""Test-suite bootstrap (shared fixtures live in the test modules)."""

@@ -413,7 +413,7 @@ def test_sc_cursor_advance_skips_empty_chunks(nfiles, ctypes, cursor):
 
 
 def test_controller_kernels_numpy_and_jax_agree():
-    from jax.experimental import enable_x64
+    from repro.eval.fabric.jaxenv import x64
 
     rng = np.random.RandomState(7)
     S, K = 32, 4
@@ -436,7 +436,7 @@ def test_controller_kernels_numpy_and_jax_agree():
     )
     ref_lag = controllers.laggard_grants(_NP, eta, n_ch, live, n_grants, 6)
     ref_w = controllers.weighted_alloc(_NP, weights, nonempty, max_cc, K)
-    with enable_x64():
+    with x64():
         import jax.numpy as jnp
 
         J = jax_ops()

@@ -13,6 +13,7 @@ The invariants under test are the ones ``eval.runner`` promises:
   * the ``build_files`` byte-bounded LRU survives concurrent access;
   * AOT-warmed signatures serve runs without a fresh jit trace.
 """
+import os
 import subprocess
 import sys
 import threading
@@ -74,7 +75,6 @@ def test_executor_mode_resolution(monkeypatch):
 def test_donation_resolution(monkeypatch):
     monkeypatch.delenv("REPRO_FABRIC_DONATE", raising=False)
     monkeypatch.delenv("REPRO_FABRIC_EXECUTOR", raising=False)
-    monkeypatch.setattr(jax_backend, "_persistent_cache_active", lambda: False)
     assert jax_backend.donation_enabled() is True  # async default
     monkeypatch.setenv("REPRO_FABRIC_EXECUTOR", "serial")
     assert jax_backend.donation_enabled() is False  # historical path
@@ -86,54 +86,27 @@ def test_donation_resolution(monkeypatch):
     assert jax_backend.donation_enabled(True) is False  # env beats kwarg
 
 
-def test_donation_survives_persistent_cache(monkeypatch):
-    """The PR 7 guard blanket-disabled donation whenever a persistent
-    compilation cache dir was configured (donated executables don't
-    survive its serialize/deserialize round trip on jax 0.4.x CPU).
-    Narrowed: freshly-compiled donated programs are correct — they now
-    compile inside the cache-suppression window and never enter the
-    cache — so ``donation_enabled`` must resolve exactly as it does
-    without a cache dir; only a cache-READ executable (a failed donated
-    warm) falls back undonated, per-signature, inside ``_device_call``.
-    The env override still beats everything both ways."""
-    monkeypatch.delenv("REPRO_FABRIC_DONATE", raising=False)
-    monkeypatch.delenv("REPRO_FABRIC_EXECUTOR", raising=False)
-    monkeypatch.setattr(jax_backend, "_persistent_cache_active", lambda: True)
-    assert jax_backend.donation_enabled() is True  # async default, cache on
-    assert jax_backend.donation_enabled(True) is True
-    monkeypatch.setenv("REPRO_FABRIC_EXECUTOR", "serial")
-    assert jax_backend.donation_enabled() is False  # serial still undonated
-    monkeypatch.setenv("REPRO_FABRIC_DONATE", "0")
-    monkeypatch.delenv("REPRO_FABRIC_EXECUTOR", raising=False)
-    assert jax_backend.donation_enabled() is False  # kill switch wins
-    monkeypatch.setenv("REPRO_FABRIC_DONATE", "1")
-    monkeypatch.setenv("REPRO_FABRIC_EXECUTOR", "serial")
-    assert jax_backend.donation_enabled() is True  # force wins
-
-
-def test_suppress_persistent_cache_restores_config(monkeypatch):
-    """The donated-compile suppression window must clear the configured
-    cache dir for its duration (nested re-entry included) and restore
-    it exactly afterwards — including on the error path."""
+def test_donation_survives_persistent_cache(monkeypatch, tmp_path):
+    """A configured persistent compilation cache leaves donation exactly
+    as it resolves without one (donated programs are cached like any
+    other); the env override still beats everything both ways."""
     import jax
 
+    monkeypatch.delenv("REPRO_FABRIC_DONATE", raising=False)
+    monkeypatch.delenv("REPRO_FABRIC_EXECUTOR", raising=False)
     before = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", "/tmp/fabric-test-cache")
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
     try:
-        with jax_backend._suppress_persistent_cache():
-            assert jax.config.jax_compilation_cache_dir is None
-            with jax_backend._suppress_persistent_cache():  # refcounted
-                assert jax.config.jax_compilation_cache_dir is None
-            assert jax.config.jax_compilation_cache_dir is None
-        assert (
-            jax.config.jax_compilation_cache_dir == "/tmp/fabric-test-cache"
-        )
-        with pytest.raises(RuntimeError):
-            with jax_backend._suppress_persistent_cache():
-                raise RuntimeError("boom")
-        assert (
-            jax.config.jax_compilation_cache_dir == "/tmp/fabric-test-cache"
-        )
+        assert jax_backend.donation_enabled() is True  # async default
+        assert jax_backend.donation_enabled(True) is True
+        monkeypatch.setenv("REPRO_FABRIC_EXECUTOR", "serial")
+        assert jax_backend.donation_enabled() is False  # serial undonated
+        monkeypatch.setenv("REPRO_FABRIC_DONATE", "0")
+        monkeypatch.delenv("REPRO_FABRIC_EXECUTOR", raising=False)
+        assert jax_backend.donation_enabled() is False  # kill switch wins
+        monkeypatch.setenv("REPRO_FABRIC_DONATE", "1")
+        monkeypatch.setenv("REPRO_FABRIC_EXECUTOR", "serial")
+        assert jax_backend.donation_enabled() is True  # force wins
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
 
@@ -238,29 +211,115 @@ def test_donation_on_off_identical_results():
         assert a.n_events == b.n_events
 
 
-def test_donated_run_correct_with_cache_dir_configured(tmp_path):
-    """The narrowed guard's end-to-end claim: with a persistent
-    compilation cache dir CONFIGURED, a donated run still produces
-    results identical to the undonated one (its programs compile inside
-    the suppression window and never round-trip the cache), and the
-    cache dir is intact afterwards."""
+@pytest.fixture
+def fresh_compile_cache(tmp_path):
+    """Point JAX's persistent compilation cache at an empty directory for
+    one test (every program is written), then restore the previous
+    configuration."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    saved = {
+        k: getattr(jax.config, k)
+        for k in (
+            "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes",
+        )
+    }
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cc.reset_cache()
+    try:
+        yield tmp_path
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+def _cached_files(path):
+    return sorted(p.name for p in path.iterdir() if p.is_file())
+
+
+def test_arm_compile_cache_placement(monkeypatch, tmp_path):
+    """Entry points keep the cache where JAX_COMPILATION_CACHE_DIR says
+    (JAX reads it itself), else at the fixed checkout path."""
+    import jax
+
+    from repro.eval.fabric import jaxenv
+
+    assert jaxenv.CHECKOUT_CACHE_DIR.endswith(os.path.join("", ".jax_cache"))
+    assert os.path.isdir(
+        os.path.join(os.path.dirname(jaxenv.CHECKOUT_CACHE_DIR), "src")
+    )
+    keys = (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
+    )
+    saved = {k: getattr(jax.config, k) for k in keys}
+    made = []
+    monkeypatch.setattr(jaxenv.os, "makedirs", lambda p, **kw: made.append(p))
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        assert jaxenv.arm_compile_cache() == str(tmp_path)
+        assert made == []
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert jaxenv.arm_compile_cache() == jaxenv.CHECKOUT_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == (
+            jaxenv.CHECKOUT_CACHE_DIR
+        )
+        assert made == [jaxenv.CHECKOUT_CACHE_DIR]
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+
+
+def test_donated_run_correct_with_cache_dir_configured(fresh_compile_cache):
+    """With a persistent compilation cache configured, donated programs
+    are written to it and a donated run matches the undonated one."""
     import jax
 
     m = _mixed_batch(4)
     sims = lambda: [build_simulation(sc) for sc in m]  # noqa: E731
     names = [sc.name for sc in m]
-    before = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
-    try:
-        on = JaxFabricSimulation(sims(), names=names, donate=True).run()
-        off = JaxFabricSimulation(sims(), names=names, donate=False).run()
-    finally:
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
-        jax.config.update("jax_compilation_cache_dir", before)
+    jax.clear_caches()
+    on = JaxFabricSimulation(sims(), names=names, donate=True).run()
+    off = JaxFabricSimulation(sims(), names=names, donate=False).run()
+    assert any(
+        "device_rounds" in f for f in _cached_files(fresh_compile_cache)
+    )
     for a, b in zip(on, off):
         assert a.total_time == b.total_time
         assert a.total_bytes == b.total_bytes
         assert a.n_events == b.n_events
+
+
+def test_donated_program_read_back_from_cache(fresh_compile_cache):
+    """The stale-alias regression: a donated executable *read back* from
+    the persistent cache (in-memory caches cleared, so nothing is
+    recompiled) must give the undonated results, run after run — jax
+    0.4.37 on CPU aliased stale buffers on exactly this path."""
+    import jax
+
+    m = _mixed_batch(6)
+    sims = lambda: [build_simulation(sc) for sc in m]  # noqa: E731
+    names = [sc.name for sc in m]
+    off = JaxFabricSimulation(sims(), names=names, donate=False).run()
+    jax.clear_caches()
+    JaxFabricSimulation(sims(), names=names, donate=True).run()
+    written = _cached_files(fresh_compile_cache)
+    for _ in range(3):
+        jax.clear_caches()
+        on = JaxFabricSimulation(sims(), names=names, donate=True).run()
+        for a, b in zip(on, off):
+            assert (a.total_time, a.total_bytes, a.n_events) == (
+                b.total_time, b.total_bytes, b.n_events
+            )
+    # the reruns were served from disk: nothing new was compiled
+    assert _cached_files(fresh_compile_cache) == written
 
 
 # ------------------------------------------------------------------ #
@@ -420,7 +479,7 @@ def test_signature_shapes_matches_real_upload():
     """The AOT aval table must mirror ``_upload`` exactly — a drifted
     dtype or axis silently downgrades every warmed signature to a jit
     fallback (or worse, a runtime mismatch)."""
-    from jax.experimental import enable_x64
+    from repro.eval.fabric.jaxenv import x64
 
     from repro.eval.fabric.bucketing import qsizes_pad
 
@@ -439,7 +498,7 @@ def test_signature_shapes_matches_real_upload():
         drv._grow_prepend()
     drv._stall = np.zeros(drv.S, dtype=np.int64)
     drv._q_pad = qsizes_pad(drv.qsizes.shape[0])
-    with enable_x64():
+    with x64():
         mut, const = drv._upload()
     em, ec, eq = jax_backend.signature_shapes(drv._rounds_signature())
     assert set(mut) == set(em) and set(const) == set(ec)

@@ -222,3 +222,22 @@ def test_warm_loop_stop_drops_pending():
     t.join(timeout=10)
     assert not t.is_alive()
     assert warmed == []
+
+
+def test_warm_loop_failure_fails_the_pipeline():
+    # a warm that raises is a fault, not a prefetch miss: it reaches the
+    # pipeline's fail hook (or the caller, without one)
+    from repro.eval.fabric.executor import _warm_loop
+
+    def bad_warm(driver):
+        raise RuntimeError(f"compiler refused {driver}")
+
+    failures = []
+    q = queue.Queue()
+    q.put("a")
+    q.put(None)
+    _warm_loop(q, threading.Event(), bad_warm, failures.append)
+    assert [str(e) for e in failures] == ["compiler refused a"]
+    q.put("b")
+    with pytest.raises(RuntimeError, match="compiler refused b"):
+        _warm_loop(q, threading.Event(), bad_warm)

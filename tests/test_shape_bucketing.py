@@ -262,17 +262,31 @@ def test_pallas_call_cached_per_shape():
     assert wf_pallas._build_call.cache_info().hits == 1
 
 
+@pytest.mark.parametrize(
+    "kwarg", [{"waterfill_impl": "pallas"}, {"fused_step": "pallas"}]
+)
+def test_f64_pallas_refused_at_selection_on_tpu(monkeypatch, kwarg):
+    """TPU's kernel compiler has no f64: selecting either f64 kernel
+    there fails when the driver is built, not mid-sweep."""
+    monkeypatch.setattr(wf_pallas.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="float64 Pallas kernel"):
+        FabricSimulation([build_simulation(_SMALL)], names=["a"], **kwarg)
+
+
 def test_fused_pallas_step_matches_classic_driver():
     """REPRO_FABRIC_FUSED_STEP=pallas routes resume-free sweeps through
     the single fused kernel; results must match the split-kernel NumPy
-    path (identical math modulo the bisected water level, ~1e-12)."""
+    path running the same bisected water level (the Pallas water-fill).
+    Against the closed-form level the ~1e-12 difference can split a
+    near-coincident pair of events, so event counts would differ."""
     scs = [
         _SMALL,
         Scenario(network=testbeds.XSEDE.name, dataset="mixed",
                  algorithm="mc", max_cc=16),
     ]
     classic = FabricSimulation(
-        [build_simulation(s) for s in scs], names=[s.name for s in scs]
+        [build_simulation(s) for s in scs], names=[s.name for s in scs],
+        waterfill_impl="pallas",
     ).run()
     fused = FabricSimulation(
         [build_simulation(s) for s in scs],

@@ -84,12 +84,12 @@ def test_waterfill_coupled_matches_progressive_filling(rows, links, seed):
 @settings(max_examples=40, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=2**31 - 1))
 def test_waterfill_coupled_numpy_and_jax_agree(seed):
-    from jax.experimental import enable_x64
+    from repro.eval.fabric.jaxenv import x64
 
     rng = np.random.RandomState(seed)
     demand, member, link_cap = _random_coupling(rng, 8, 3)
     ref, ref_levels = kernels.waterfill_coupled(_NP, demand, member, link_cap)
-    with enable_x64():
+    with x64():
         import jax.numpy as jnp
 
         x, levels = kernels.waterfill_coupled(

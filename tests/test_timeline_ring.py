@@ -96,13 +96,13 @@ def test_timeline_push_numpy_jax_bit_identical(n, budget):
     """The same sample stream records bit-identically on both ArrayOps
     instantiations (the kernel is pure selects — no float arithmetic)."""
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from repro.eval.fabric.jaxenv import x64
 
     rng = np.random.RandomState(n * 1000 + budget)
     ts = np.cumsum(rng.uniform(1e-3, 10.0, size=n))
     samples = [(float(t), float(rng.uniform(0, 1e9))) for t in ts]
     np_state = _record_stream(numpy_ops(), np, samples, budget)
-    with enable_x64():
+    with x64():
         jx_state = _record_stream(jax_ops(), jnp, samples, budget)
     for a, b in zip(np_state, jx_state):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
