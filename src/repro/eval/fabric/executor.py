@@ -47,7 +47,7 @@ import queue
 import threading
 from typing import Callable, List, Optional, Sequence
 
-from .stats import wall_timer
+from .stats import span
 
 #: recognised ``REPRO_FABRIC_EXECUTOR`` values
 EXECUTOR_MODES = ("serial", "async")
@@ -185,8 +185,9 @@ def execute_chunks(
     guaranteed to be.
 
     Chunk build and driver-run wall time accumulate into the shared
-    ``stats.SYNC_STATS`` wall keys in every mode, so the prep-vs-compute
-    breakdown (``runner --verbose``) measures the host build tax.
+    ``stats.SYNC_STATS`` wall keys in every mode (spans ``fabric.build``
+    and ``fabric.run``), so the prep-vs-compute breakdown
+    (``runner --verbose``) measures the host build tax.
     """
     mode = executor_mode(mode)
     parts = [list(p) for p in parts]
@@ -203,9 +204,9 @@ def execute_chunks(
 
     if mode == "serial" or len(parts) <= 0:
         for part in parts:
-            with wall_timer("build_wall_s"):
+            with span("fabric.build", "build_wall_s"):
                 driver = make_chunk(part, None)
-            with wall_timer("compute_wall_s"):
+            with span("fabric.run", "compute_wall_s"):
                 out = driver.run()
             for i, res in zip(part, out):
                 results[i] = res
@@ -259,7 +260,7 @@ def execute_chunks(
                         return
                     next_j[0] = j + 1
                 dev = devices[j % len(devices)]
-                with wall_timer("build_wall_s"):
+                with span("fabric.build", "build_wall_s"):
                     driver = make_chunk(parts[j], dev)
                 if placed:
                     warm_qs[j % len(devices)].put(driver)
@@ -277,7 +278,7 @@ def execute_chunks(
                 continue  # keep draining so prep's puts can't wedge
             part, driver = item
             try:
-                with wall_timer("compute_wall_s"):
+                with span("fabric.run", "compute_wall_s"):
                     out = driver.run()
                 # distinct indices per chunk: concurrent writes are safe
                 for i, res in zip(part, out):

@@ -66,7 +66,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -120,6 +119,7 @@ from .stats import (  # noqa: E402,F401  (re-exported API)
     _SYNC_LOCK,
     _merge_sync_stats,
     reset_sync_stats,
+    span,
 )
 
 
@@ -562,6 +562,18 @@ def _phase_move(row: dict, qsizes):
 #: aliasing below, cover exactly the state that changes.
 _CARRY = _MUTABLE + _SCRATCH
 
+#: ``jax.named_scope`` names of the loop body's phases: they reach every
+#: op's ``op_name`` in the compiled HLO (``compiled.as_text()``), which
+#: ties each fusion a device trace names to a phase; the trace's own op
+#: events carry no such metadata. A: advance and feed; B: the completion
+#: drain; C: controller ticks; D: channel moves; the coupled loop's
+#: cross-row water-fill
+SCOPE_ADVANCE = "phase_a_advance_feed"
+SCOPE_COMPLETE = "phase_b_complete"
+SCOPE_TICK = "phase_c_tick"
+SCOPE_MOVE = "phase_d_move"
+SCOPE_WATERFILL = "coupled_waterfill"
+
 
 def _device_rounds_fn(mut: dict, const: dict, qsizes, compact_floor: int):
     """Advance every runnable scenario to its own next Python decision
@@ -623,29 +635,33 @@ def _device_rounds_fn(mut: dict, const: dict, qsizes, compact_floor: int):
         st = {**st, **const}
         # resume files are rare: feed through the pure-FIFO phase-A
         # variant unless some row's stack holds one
-        st = lax.cond(
-            jnp.any(st["prepend_n"] > 0),
-            lambda s: phase_a(s, qsizes),
-            lambda s: phase_a_fifo(s, qsizes),
-            st,
-        )
+        with jax.named_scope(SCOPE_ADVANCE):
+            st = lax.cond(
+                jnp.any(st["prepend_n"] > 0),
+                lambda s: phase_a(s, qsizes),
+                lambda s: phase_a_fifo(s, qsizes),
+                st,
+            )
         # drain completed chunks: each iteration handles every row's
         # lowest-index remaining completion (ascending k per row, the
         # host _complete_ctrl order) and clears it, so the loop runs
         # exactly as deep as the worst row's completion count — zero
         # iterations on the common no-completion sweep
-        st = lax.while_loop(
-            lambda s: jnp.any(s["_completed"] & s["_handler"][:, None]),
-            lambda s: phase_b(s, qsizes),
-            st,
-        )
-        st = lax.cond(
-            jnp.any(st["_tick"]), phase_c, lambda s: s, st
-        )
-        st = lax.cond(
-            jnp.any(st["_moving"]), lambda s: phase_d(s, qsizes),
-            lambda s: s, st,
-        )
+        with jax.named_scope(SCOPE_COMPLETE):
+            st = lax.while_loop(
+                lambda s: jnp.any(s["_completed"] & s["_handler"][:, None]),
+                lambda s: phase_b(s, qsizes),
+                st,
+            )
+        with jax.named_scope(SCOPE_TICK):
+            st = lax.cond(
+                jnp.any(st["_tick"]), phase_c, lambda s: s, st
+            )
+        with jax.named_scope(SCOPE_MOVE):
+            st = lax.cond(
+                jnp.any(st["_moving"]), lambda s: phase_d(s, qsizes),
+                lambda s: s, st,
+            )
         return {k: st[k] for k in _CARRY}, it + 1
 
     state, iters = lax.while_loop(cond, body, (dict(mut), 0))
@@ -778,35 +794,41 @@ def _device_rounds_coupled_fn(
     def body(carry):
         st, it = carry
         st = {**st, **const}
-        live, pool, demand = demand_v(st)
-        grant, _ = kernels.waterfill_coupled(
-            ops, jnp.where(live & in_group, demand, 0.0), member, link_cap
-        )
-        pool_ovr = jnp.where(in_group, grant, pool)
-        dt_own = horizon_v(st, pool_ovr)
-        g_dt = (
-            jnp.full((G,), jnp.inf)
-            .at[gclip]
-            .min(jnp.where(live & in_group, dt_own, jnp.inf))
-        )
-        st["_pool_ovr"] = pool_ovr
-        st["_dt_ovr"] = jnp.where(in_group, g_dt[gclip], jnp.inf)
-        st = lax.cond(
-            jnp.any(st["prepend_n"] > 0),
-            lambda s: phase_a(s, qsizes),
-            lambda s: phase_a_fifo(s, qsizes),
-            st,
-        )
-        st = lax.while_loop(
-            lambda s: jnp.any(s["_completed"] & s["_handler"][:, None]),
-            lambda s: phase_b(s, qsizes),
-            st,
-        )
-        st = lax.cond(jnp.any(st["_tick"]), phase_c, lambda s: s, st)
-        st = lax.cond(
-            jnp.any(st["_moving"]), lambda s: phase_d(s, qsizes),
-            lambda s: s, st,
-        )
+        with jax.named_scope(SCOPE_WATERFILL):
+            live, pool, demand = demand_v(st)
+            grant, _ = kernels.waterfill_coupled(
+                ops, jnp.where(live & in_group, demand, 0.0), member,
+                link_cap,
+            )
+            pool_ovr = jnp.where(in_group, grant, pool)
+            dt_own = horizon_v(st, pool_ovr)
+            g_dt = (
+                jnp.full((G,), jnp.inf)
+                .at[gclip]
+                .min(jnp.where(live & in_group, dt_own, jnp.inf))
+            )
+            st["_pool_ovr"] = pool_ovr
+            st["_dt_ovr"] = jnp.where(in_group, g_dt[gclip], jnp.inf)
+        with jax.named_scope(SCOPE_ADVANCE):
+            st = lax.cond(
+                jnp.any(st["prepend_n"] > 0),
+                lambda s: phase_a(s, qsizes),
+                lambda s: phase_a_fifo(s, qsizes),
+                st,
+            )
+        with jax.named_scope(SCOPE_COMPLETE):
+            st = lax.while_loop(
+                lambda s: jnp.any(s["_completed"] & s["_handler"][:, None]),
+                lambda s: phase_b(s, qsizes),
+                st,
+            )
+        with jax.named_scope(SCOPE_TICK):
+            st = lax.cond(jnp.any(st["_tick"]), phase_c, lambda s: s, st)
+        with jax.named_scope(SCOPE_MOVE):
+            st = lax.cond(
+                jnp.any(st["_moving"]), lambda s: phase_d(s, qsizes),
+                lambda s: s, st,
+            )
         return {k: st[k] for k in _CARRY}, it + 1
 
     state, iters = lax.while_loop(cond, body, (dict(mut), 0))
@@ -1340,29 +1362,42 @@ class JaxFabricSimulation(FabricSimulation):
         # qoff+qlen, so the pad slots are dead weight (8 B each), not
         # semantics
         self._q_pad = qsizes_pad(self.qsizes.shape[0])
-        qsizes_dev = self._to_device(
-            np.concatenate(
-                [self.qsizes, np.zeros(self._q_pad - self.qsizes.shape[0])]
+        with span("fabric.upload", "upload_wall_s", stats):
+            qsizes_dev = self._to_device(
+                np.concatenate(
+                    [self.qsizes,
+                     np.zeros(self._q_pad - self.qsizes.shape[0])]
+                )
             )
-        )
-        if self.coupled:
-            self._fab_dev = self._upload_fabric()
+            if self.coupled:
+                self._fab_dev = self._upload_fabric()
         try:
             while not self.done.all():
                 progressed = False
                 runnable = ~self.done & (self._stall == _STALL_NONE)
                 if runnable.any():
-                    mut, const = self._upload()
-                    state, iters = self._device_call(mut, const, qsizes_dev)
+                    # each round is three spans: host buffers enqueued,
+                    # the device call until its outputs are ready, and
+                    # the ready outputs copied back. Blocking inside the
+                    # device span keeps the loop's own time out of the
+                    # download, whose first host read would wait for it
+                    with span("fabric.upload", "upload_wall_s", stats):
+                        mut, const = self._upload()
+                    with span("fabric.device", "device_wall_s", stats):
+                        state, iters = self._device_call(
+                            mut, const, qsizes_dev
+                        )
+                        jax.block_until_ready((state, iters))
                     # donated inputs are dead past this point; the next
                     # round re-uploads from the host arrays _download
                     # refreshes, so nothing reads them again
                     del mut
-                    t0 = time.perf_counter()
-                    self._download(state)
-                    stats["download_wall_s"] += time.perf_counter() - t0
+                    with span("fabric.download", "download_wall_s", stats):
+                        self._download(state)
+                        n_iters = int(iters)
                     stats["rounds"] += 1
-                    progressed = int(iters) > 0
+                    stats["iterations"] += n_iters
+                    progressed = n_iters > 0
                 post_rows = ~self.done & (self._stall == _STALL_POST)
                 if post_rows.any():
                     # custom-scheduler callbacks (or a capacity guard a

@@ -1,4 +1,4 @@
-"""Shared pipeline telemetry: host-sync counters + wall-clock splits.
+"""Shared pipeline telemetry: host-sync counters and wall-clock spans.
 
 Historically :data:`SYNC_STATS` lived in :mod:`repro.eval.fabric.
 jax_backend`; the executor's prep/compute wall instrumentation needs the
@@ -7,45 +7,69 @@ dict (and its lock) moved here; ``jax_backend`` re-imports the *same*
 objects, so ``jax_backend.SYNC_STATS`` keeps working and
 :func:`reset_sync_stats` (in-place) resets both views at once.
 
-Counter keys (``rounds`` .. ``runs``) keep their zero-host-round
-contract (see the jax backend docstring). The ``*_wall_s`` keys are the
-pipeline's build-tax instrumentation: host-side chunk construction
-(``build_wall_s``), driver execution (``compute_wall_s``), and — on the
-jax backend — the device->host result downloads inside the drive loop
-(``download_wall_s``). Wall keys are float seconds and overlap freely
-(several prep/compute threads accumulate concurrently), so they measure
-aggregate thread-time per phase, not elapsed wall clock; their ratio is
-what the prep-vs-compute breakdown under ``runner --verbose`` reports.
+Counter keys keep their zero-host-round contract (see the jax backend
+docstring); ``iterations`` sums the device ``while_loop``'s iterations
+over rounds. The ``*_wall_s`` keys are filled by :func:`span`, one named
+span each:
+
+  ======================  ===================  ===========================
+  span                    wall key             what it times
+  ======================  ===================  ===========================
+  ``fabric.build``        ``build_wall_s``     host chunk construction
+  ``fabric.run``          ``compute_wall_s``   one driver ``run()``
+  ``fabric.upload``       ``upload_wall_s``    a round's host buffers built
+                                               and enqueued to the device
+  ``fabric.device``       ``device_wall_s``    a round's device call until
+                                               its outputs are ready
+  ``fabric.download``     ``download_wall_s``  a round's ready outputs
+                                               copied to the host
+  ======================  ===================  ===========================
+
+The last three are jax-backend rounds, nested inside ``fabric.run``;
+``download_wall_s`` holds transfer and host copy only, the device's own
+time being in ``device_wall_s``. Wall keys are float seconds and
+overlap freely (several prep/compute threads accumulate concurrently),
+so they measure aggregate thread-time per phase, not elapsed wall clock;
+their ratio is what the breakdown under ``runner --verbose`` reports.
 """
 from __future__ import annotations
 
+import sys
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
+from typing import Optional
 
 #: wall-clock accumulator keys: float seconds, thread-time semantics.
 #: Everything else in SYNC_STATS is an exact integer counter — tests that
 #: pin counter equality across execution modes must exclude these.
 WALL_KEYS = frozenset(
-    {"build_wall_s", "compute_wall_s", "download_wall_s"}
+    {
+        "build_wall_s", "compute_wall_s", "upload_wall_s",
+        "device_wall_s", "download_wall_s",
+    }
 )
 
 #: host-sync telemetry, accumulated across runs (reset with
 #: :func:`reset_sync_stats`); the eval-matrix bench derives its
 #: device-syncs-per-scenario figure from this. ``rounds`` counts device
-#: while_loop entries (compaction/straggler re-entries included);
-#: ``replay_rounds`` counts only rounds that ended with the host
-#: replaying ``_post`` for parked rows, and ``post_row_replays`` the
-#: parked rows themselves — both exactly 0 for built-in schedulers, the
-#: zero-host-round invariant CI gates on.
+#: while_loop entries (compaction/straggler re-entries included) and
+#: ``iterations`` the loop iterations they ran; ``replay_rounds`` counts
+#: only rounds that ended with the host replaying ``_post`` for parked
+#: rows, and ``post_row_replays`` the parked rows themselves — both
+#: exactly 0 for built-in schedulers, the zero-host-round invariant CI
+#: gates on.
 SYNC_STATS = {
     "rounds": 0,
+    "iterations": 0,
     "replay_rounds": 0,
     "post_row_replays": 0,
     "scenarios": 0,
     "runs": 0,
     "build_wall_s": 0.0,
     "compute_wall_s": 0.0,
+    "upload_wall_s": 0.0,
+    "device_wall_s": 0.0,
     "download_wall_s": 0.0,
 }
 
@@ -68,17 +92,26 @@ def _merge_sync_stats(local: dict) -> None:
             SYNC_STATS[k] += v
 
 
-def record_wall(key: str, seconds: float) -> None:
-    """Accumulate ``seconds`` into wall key ``key`` (thread-safe)."""
-    with _SYNC_LOCK:
-        SYNC_STATS[key] += seconds
-
-
 @contextmanager
-def wall_timer(key: str):
-    """Context manager accumulating the enclosed block's wall time."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        record_wall(key, time.perf_counter() - t0)
+def span(name: str, key: str, into: Optional[dict] = None):
+    """Time the enclosed block into wall key ``key`` and, once jax is
+    imported, open a ``jax.profiler.TraceAnnotation`` named ``name``
+    around it: inside a profiler trace the span lands on the host plane,
+    on the line of the thread that opened it, on the device planes'
+    clock. ``into`` is a private per-run accumulator merged later with
+    :func:`_merge_sync_stats`; without it the time goes straight into
+    :data:`SYNC_STATS` (thread-safe). NumPy-only runs never import jax,
+    so they time without annotating."""
+    jax = sys.modules.get("jax")
+    ann = jax.profiler.TraceAnnotation(name) if jax else nullcontext()
+    with ann:
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            dt = time.perf_counter() - t0
+            if into is not None:
+                into[key] += dt
+            else:
+                with _SYNC_LOCK:
+                    SYNC_STATS[key] += dt

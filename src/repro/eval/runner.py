@@ -550,19 +550,25 @@ def run_tune(args, scenarios: Sequence[Scenario]) -> int:
 def _print_wall_breakdown() -> None:
     """The ``--verbose`` prep-vs-compute split: aggregate thread-seconds
     per pipeline phase from the shared wall accumulators (phases overlap
-    under the async executor, so they need not sum to elapsed time)."""
+    under the async executor, so they need not sum to elapsed time), and
+    the jax backend's device rounds split into upload, device call and
+    download inside compute."""
     from .fabric import stats as fabric_stats
 
     s = dict(fabric_stats.SYNC_STATS)
     build = s["build_wall_s"]
     compute = s["compute_wall_s"]
-    download = s["download_wall_s"]
     total = max(build + compute, 1e-9)
+    rounds = " + ".join(
+        f"{part} {s[part + '_wall_s']:.3f}s"
+        for part in ("upload", "device", "download")
+    )
     print(
         "wall breakdown (thread-seconds, phases overlap): "
         f"build {build:.3f}s ({100.0 * build / total:.1f}%) | "
         f"compute {compute:.3f}s ({100.0 * compute / total:.1f}%) | "
-        f"download {download:.3f}s (inside compute)"
+        f"inside compute: {rounds} over {s['rounds']} rounds, "
+        f"{s['iterations']} loop iterations"
     )
 
 
@@ -591,7 +597,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument(
         "--verbose", action="store_true",
         help="print the prep-vs-compute wall breakdown (host chunk "
-        "build, driver run, device->host downloads) after the run",
+        "build, driver run, and its device rounds' upload, device call "
+        "and download) after the run",
     )
     ap.add_argument(
         "--tune", choices=("oracle", "sha", "hill"), default=None,
