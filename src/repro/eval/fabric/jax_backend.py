@@ -52,8 +52,14 @@ Execution-loop structure (the overlap-pipelined executor rides on it):
     :func:`donation_enabled` says so (default: on under the async
     executor, forced via ``REPRO_FABRIC_DONATE``), so steady-state sweeps
     update device buffers in place instead of allocating a second copy.
-    Donated buffers are dead after the call — the driver re-uploads from
-    host NumPy each round and never touches a donated array again;
+    Donated buffers are dead after the call — the driver never touches a
+    donated array again;
+  * the carry stays **resident** on the device between rounds: one
+    round's output state is the next round's ``mut``, and the host reads
+    only the ``done``/``stall``/``err`` flags and the iteration count.
+    The whole state comes back (``state_syncs``) only when the host needs
+    it — a parked row's replay, a compaction, an error, the end of the
+    run — and a replay or compaction re-uploads from host NumPy;
   * each batch can be pinned to a device (``device=``) — the executor
     round-robins chunks across ``jax.devices()``;
   * :func:`warm_signature` AOT-compiles (``jit(...).lower().compile()``)
@@ -610,15 +616,15 @@ def _device_rounds_fn(mut: dict, const: dict, qsizes, compact_floor: int):
     # the row axis is a static jit shape: whether an early exit can ever
     # lead anywhere is decided at trace time. Rows at (or below) this
     # batch's compaction floor can't shrink their device shape, so
-    # exiting early would buy a full state download/re-upload for
-    # nothing — those programs run to completion (or the sweep cap).
+    # exiting early would buy a round boundary for nothing — those
+    # programs run to completion (or the sweep cap).
     # Above the floor the exit fraction follows the floor itself:
     # heterogeneous grid batches (deep ladder, floor 64) exit once half
     # the starting cohort has drained — straggler tails get compacted
     # down the rungs promptly — while all-static plane batches (shallow
     # ladder, floor 256) ride to a quarter cohort before syncing, since
-    # their rows drain nearly together and each exit is a full state
-    # download/re-upload.
+    # their rows drain nearly together and each compaction is a full
+    # state download/re-upload.
     can_shrink = mut["done"].shape[0] > compact_floor
     exit_div = 2 if compact_floor < 256 else 4
 
@@ -674,8 +680,9 @@ def _device_rounds_fn(mut: dict, const: dict, qsizes, compact_floor: int):
 #: at trace time)
 _device_rounds = jax.jit(_device_rounds_fn, static_argnums=3)
 #: the donated twin: the mutable carry updates in place, halving the
-#: loop's peak device footprint. The driver re-uploads from host NumPy
-#: every round, so donated inputs are never read again.
+#: loop's peak device footprint. The driver passes each round's output
+#: (or a fresh upload) as the next input, so donated inputs are never
+#: read again.
 _device_rounds_donated = jax.jit(
     _device_rounds_fn, donate_argnums=0, static_argnums=3
 )
@@ -1118,11 +1125,14 @@ def compiled_program_count() -> int:
 class JaxFabricSimulation(FabricSimulation):
     """FabricSimulation driven by the jit/vmap device loop.
 
-    Host state (the parent's NumPy arrays) stays canonical; each round
-    uploads it, lets the device run every scenario to its next decision
-    point (usually: completion), downloads, and replays the parent's
-    Python half for parked rows. Custom-scheduler bookkeeping (callback
-    objects, views) is inherited unchanged.
+    Each round lets the device run every scenario to its next decision
+    point (usually: completion) or the round cap. Between rounds the
+    state stays on the device and the host reads only its flags; the
+    parent's NumPy arrays are brought up to date (:meth:`_sync_host`)
+    before the host replays the parent's Python half for parked rows,
+    compacts, reports an error or assembles results, and the round after
+    a replay or compaction uploads them again. Custom-scheduler
+    bookkeeping (callback objects, views) is inherited unchanged.
 
     ``device`` pins every upload (and the AOT executable) to one
     ``jax.Device`` — the executor round-robins chunks across
@@ -1175,10 +1185,11 @@ class JaxFabricSimulation(FabricSimulation):
         return jnp.asarray(arr)
 
     def _upload(self) -> Tuple[dict, dict]:
-        """Fresh device buffers for one round: ``(mut, const)``. ``mut``
-        is rebuilt from host NumPy every round — which is what makes
-        donating it safe — while the read-only ``const`` tables are
-        device-cached until compaction/growth reshapes the rows."""
+        """Fresh device buffers for a round that starts from the host
+        arrays: ``(mut, const)``. ``mut`` is rebuilt from host NumPy —
+        which is what makes donating it safe — while the read-only
+        ``const`` tables are device-cached until compaction/growth
+        reshapes the rows."""
         pad = self._pad_rows() - self.S
         rows = self.S + pad
         mut = {}
@@ -1269,30 +1280,42 @@ class JaxFabricSimulation(FabricSimulation):
         fn = _device_rounds_donated if self.donate else _device_rounds
         return fn(mut, const, qsizes, floor)
 
-    def _download(self, state: dict) -> None:
-        for key in _MUTABLE:
-            if key == "err":
-                continue
-            # np.array (not asarray): device buffers are zero-copy
-            # read-only views, and the host half mutates these in place.
-            # Pad rows are dropped on the host: slicing the device array
-            # would compile a slice program per (shape, row count, device)
-            arr = np.array(state[key])[: self.S]
-            setattr(self, "_stall" if key == "stall" else key, arr)
-        err = np.asarray(state["err"])[: self.S]
-        if err.any():
-            s = int(np.flatnonzero(err)[0])
-            if err[s] == _ERR_MAXTIME:
-                raise RuntimeError(
-                    f"batch scenario {self.rt[s].name!r} exceeded max_time="
-                    f"{self.max_time[s]}s (t={self.t[s]:.1f})"
-                )
-            r = self.rt[s]
-            live = np.flatnonzero(~self.chunk_done[s])
-            raise RuntimeError(
-                f"scheduler {r.scheduler.name} stranded chunks "
-                f"{[r.chunks[int(k)].name for k in live]} in {r.name!r}"
+    def _sync_host(self, state: dict, stats: dict) -> None:
+        """Bring the host arrays up to date from a round's device state,
+        in one batched transfer: the host replays, compactions, error
+        messages and results read them. Counts one ``state_syncs``."""
+        with span("fabric.download", "download_wall_s", stats):
+            host = jax.device_get(
+                {key: state[key] for key in _MUTABLE if key != "err"}
             )
+            for key, arr in host.items():
+                # a writable copy: device buffers come back as read-only
+                # views, and the host half mutates these in place. Pad
+                # rows are dropped on the host: slicing the device array
+                # would compile a slice program per (shape, row count,
+                # device)
+                setattr(
+                    self, "_stall" if key == "stall" else key,
+                    np.array(arr[: self.S]),
+                )
+        stats["state_syncs"] += 1
+
+    def _raise_err(self, err: np.ndarray) -> None:
+        """Raise for the first row whose device ``err`` flag is set; the
+        host arrays must be synced, the message reads ``t`` and
+        ``chunk_done``."""
+        s = int(np.flatnonzero(err)[0])
+        if err[s] == _ERR_MAXTIME:
+            raise RuntimeError(
+                f"batch scenario {self.rt[s].name!r} exceeded max_time="
+                f"{self.max_time[s]}s (t={self.t[s]:.1f})"
+            )
+        r = self.rt[s]
+        live = np.flatnonzero(~self.chunk_done[s])
+        raise RuntimeError(
+            f"scheduler {r.scheduler.name} stranded chunks "
+            f"{[r.chunks[int(k)].name for k in live]} in {r.name!r}"
+        )
 
     # -------------------------------------------------------------- #
 
@@ -1312,9 +1335,10 @@ class JaxFabricSimulation(FabricSimulation):
             self._drive()
         return [self._result(r) for r in all_rt]
 
-    def _maybe_compact(self) -> None:
+    def _compaction_due(self) -> bool:
         """Compaction policy for the device loop: one deterministic
-        quarter-step rung, then stop.
+        quarter-step rung, then stop. Decided from ``done`` alone, so
+        the host syncs the device state only when a rung is due.
 
         The parent compacts whenever half the batch is done — right for
         NumPy, where a rebuild is free and sweep cost tracks live rows.
@@ -1336,12 +1360,16 @@ class JaxFabricSimulation(FabricSimulation):
         if self.coupled:
             # frozen row set: membership table, group ids, and the one
             # compiled coupled program stay valid for the whole run
-            return
-        floor = self.compact_floor()
+            return False
         live = self.S - int(self.done.sum())
         pad = self._pad_rows()
-        if pad > floor and bucket(live, _MIN_PAD) * 4 <= pad:
-            self._pad_floor = max(pad // 4, floor)
+        floor = self.compact_floor()
+        return pad > floor and bucket(live, _MIN_PAD) * 4 <= pad
+
+    def _maybe_compact(self) -> None:
+        """Compact to the rung :meth:`_compaction_due` asks for."""
+        if self._compaction_due():
+            self._pad_floor = max(self._pad_rows() // 4, self.compact_floor())
             self._compact()
 
     def _drive(self) -> None:
@@ -1371,38 +1399,63 @@ class JaxFabricSimulation(FabricSimulation):
             )
             if self.coupled:
                 self._fab_dev = self._upload_fabric()
+        # the last round's device state while the host arrays are stale:
+        # it is the next round's input, and is synced before any host
+        # code reads the arrays
+        carry = None
+
+        def sync() -> None:
+            nonlocal carry
+            if carry is not None:
+                state, carry = carry, None
+                self._sync_host(state, stats)
+
         try:
             while not self.done.all():
                 progressed = False
                 runnable = ~self.done & (self._stall == _STALL_NONE)
                 if runnable.any():
-                    # each round is three spans: host buffers enqueued,
-                    # the device call until its outputs are ready, and
-                    # the ready outputs copied back. Blocking inside the
-                    # device span keeps the loop's own time out of the
-                    # download, whose first host read would wait for it
-                    with span("fabric.upload", "upload_wall_s", stats):
-                        mut, const = self._upload()
+                    # each round is up to three spans: host buffers
+                    # enqueued (only when the round starts from the host
+                    # arrays), the device call until its outputs are
+                    # ready, and the flags copied back. Blocking inside
+                    # the device span keeps the loop's own time out of
+                    # the download, whose first host read would wait
+                    if carry is None:
+                        with span("fabric.upload", "upload_wall_s", stats):
+                            mut, const = self._upload()
+                    else:
+                        mut, const, carry = carry, self._static_cache, None
                     with span("fabric.device", "device_wall_s", stats):
                         state, iters = self._device_call(
                             mut, const, qsizes_dev
                         )
                         jax.block_until_ready((state, iters))
                     # donated inputs are dead past this point; the next
-                    # round re-uploads from the host arrays _download
-                    # refreshes, so nothing reads them again
+                    # round starts from ``state`` or from the host arrays
                     del mut
+                    carry = state
                     with span("fabric.download", "download_wall_s", stats):
-                        self._download(state)
-                        n_iters = int(iters)
+                        done, stall, err, n_iters = jax.device_get(
+                            (state["done"], state["stall"], state["err"],
+                             iters)
+                        )
+                        self.done = np.array(done[: self.S])
+                        self._stall = np.array(stall[: self.S])
+                        err = err[: self.S]
+                    n_iters = int(n_iters)
                     stats["rounds"] += 1
                     stats["iterations"] += n_iters
                     progressed = n_iters > 0
+                    if err.any():
+                        sync()
+                        self._raise_err(err)
                 post_rows = ~self.done & (self._stall == _STALL_POST)
                 if post_rows.any():
                     # custom-scheduler callbacks (or a capacity guard a
                     # custom subclass defeated): replay the NumPy
-                    # transition half
+                    # transition half on synced host arrays
+                    sync()
                     stats["replay_rounds"] += 1
                     stats["post_row_replays"] += int(post_rows.sum())
                     self._post(post_rows)
@@ -1413,6 +1466,13 @@ class JaxFabricSimulation(FabricSimulation):
                         "jax fabric backend made no progress; device loop "
                         f"exited with {int(runnable.sum())} runnable rows"
                     )
-                self._maybe_compact()
+                if self._compaction_due():
+                    sync()
+                    self._maybe_compact()
         finally:
-            _merge_sync_stats(stats)
+            # the loop's end, or an exception leaving it: results and
+            # error reports read the host arrays
+            try:
+                sync()
+            finally:
+                _merge_sync_stats(stats)

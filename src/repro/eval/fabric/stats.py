@@ -9,8 +9,9 @@ objects, so ``jax_backend.SYNC_STATS`` keeps working and
 
 Counter keys keep their zero-host-round contract (see the jax backend
 docstring); ``iterations`` sums the device ``while_loop``'s iterations
-over rounds. The ``*_wall_s`` keys are filled by :func:`span`, one named
-span each:
+over rounds, and ``state_syncs`` counts whole-state copies back to the
+host. The ``*_wall_s`` keys are filled by :func:`span`, one named span
+each:
 
   ======================  ===================  ===========================
   span                    wall key             what it times
@@ -21,16 +22,37 @@ span each:
                                                and enqueued to the device
   ``fabric.device``       ``device_wall_s``    a round's device call until
                                                its outputs are ready
-  ``fabric.download``     ``download_wall_s``  a round's ready outputs
-                                               copied to the host
+  ``fabric.download``     ``download_wall_s``  a round's ready flags
+                                               read, and each whole-state
+                                               sync, copied to the host
   ======================  ===================  ===========================
 
-The last three are jax-backend rounds, nested inside ``fabric.run``;
-``download_wall_s`` holds transfer and host copy only, the device's own
-time being in ``device_wall_s``. Wall keys are float seconds and
-overlap freely (several prep/compute threads accumulate concurrently),
-so they measure aggregate thread-time per phase, not elapsed wall clock;
-their ratio is what the breakdown under ``runner --verbose`` reports.
+The last three are jax-backend rounds, nested inside ``fabric.run``.
+Between rounds the loop state stays on the device: ``upload_wall_s``
+holds only the rounds that start from the host arrays (a run's first,
+and the first after a host replay or a compaction), and
+``download_wall_s`` transfer and host copy only, the device's own time
+being in ``device_wall_s``.
+
+Counters other than the walls:
+
+  ======================  =============================================
+  counter                 what it counts
+  ======================  =============================================
+  ``rounds``              device ``while_loop`` entries
+  ``iterations``          loop iterations over those rounds
+  ``state_syncs``         whole-state copies to the host (a replay, a
+                          compaction, an error, a run's end);
+                          ``rounds - state_syncs`` rounds resumed from
+                          the device-resident state
+  ``replay_rounds``       rounds ended by a host ``_post`` replay
+  ``post_row_replays``    parked rows replayed
+  ``scenarios``/``runs``  rows and driver runs
+  ======================  =============================================
+
+Wall keys are float seconds and overlap freely (several prep/compute
+threads accumulate concurrently), so they measure aggregate thread-time
+per phase, not elapsed wall clock; their ratio is what the breakdown under ``runner --verbose`` reports.
 """
 from __future__ import annotations
 
@@ -54,7 +76,8 @@ WALL_KEYS = frozenset(
 #: :func:`reset_sync_stats`); the eval-matrix bench derives its
 #: device-syncs-per-scenario figure from this. ``rounds`` counts device
 #: while_loop entries (compaction/straggler re-entries included) and
-#: ``iterations`` the loop iterations they ran; ``replay_rounds`` counts
+#: ``iterations`` the loop iterations they ran, ``state_syncs`` the
+#: whole-state copies back to the host; ``replay_rounds`` counts
 #: only rounds that ended with the host replaying ``_post`` for parked
 #: rows, and ``post_row_replays`` the parked rows themselves — both
 #: exactly 0 for built-in schedulers, the zero-host-round invariant CI
@@ -62,6 +85,7 @@ WALL_KEYS = frozenset(
 SYNC_STATS = {
     "rounds": 0,
     "iterations": 0,
+    "state_syncs": 0,
     "replay_rounds": 0,
     "post_row_replays": 0,
     "scenarios": 0,

@@ -365,6 +365,21 @@ def test_sync_stats_interleaved_equals_serial():
     assert interleaved_stats["scenarios"] == 8
 
 
+def test_state_syncs_equal_in_serial_and_async_modes():
+    """The device loop's whole-state syncs count the same under both
+    executors: one per driver run, as no batch here compacts or parks."""
+    m = _mixed_batch(10)
+    counts = {}
+    for executor in ("serial", "async"):
+        jax_backend.reset_sync_stats()
+        run_matrix(m, backend="jax", chunk_size=4, executor=executor)
+        stats = jax_backend.SYNC_STATS
+        counts[executor] = (stats["state_syncs"], stats["runs"])
+    assert counts["serial"] == counts["async"]
+    syncs, runs = counts["serial"]
+    assert syncs == runs > 0
+
+
 # ------------------------------------------------------------------ #
 # build_files cache under concurrency
 # ------------------------------------------------------------------ #
