@@ -259,7 +259,10 @@ RAMPY_EVENING = impaired_variant(
 )
 
 # ---------------------------------------------------------------------------
-# TPU-fabric adaptation presets (DESIGN.md Sec. 2)
+# TPU-fabric adaptation presets: the paper's tuning problem mapped onto an
+# ML cluster's own transfers. Gradient buckets crossing the data-center
+# network between pods, and checkpoint shards going to object storage,
+# are the "files"; a collective group or an upload stream is a channel.
 # ---------------------------------------------------------------------------
 
 #: Cross-pod data-center network, as seen by one pod's gradient-sync engine.

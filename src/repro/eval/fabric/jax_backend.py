@@ -166,6 +166,30 @@ _CONST_STATIC = (
 )
 
 
+#: ``jax.named_scope`` of the profiled rows' capacity lookup and
+#: profile-step horizon (bandwidth-profile width B > 1 only; the static
+#: B == 1 programs never enter it). It opens inside the vmapped per-row
+#: phases, so the compiled HLO's ``op_name`` reads it as
+#: ``vmap(profile_capacity)``
+SCOPE_PROFILE = "profile_capacity"
+
+
+@jax.named_scope(SCOPE_PROFILE)
+def _profile_bw(xp, row):
+    """One profiled row's capacity at its clock ``t``: the nominal
+    bandwidth times the multiplier of the last step at or before ``t``."""
+    prof_at = xp.sum(row["prof_t"] <= row["t"]) - 1
+    mult = row["prof_mult"][xp.maximum(prof_at, 0)]
+    return row["bw"] * xp.where(prof_at >= 0, mult, 1.0)
+
+
+@jax.named_scope(SCOPE_PROFILE)
+def _next_profile_step(xp, row):
+    """Time of one profiled row's next profile step (+inf past the
+    last): an event horizon."""
+    return xp.min(xp.where(row["prof_t"] > row["t"], row["prof_t"], xp.inf))
+
+
 def _views_row(ops, xp, row, chunk_of, busy, rem, queue_bytes, rate_est, K):
     """Per-row ChunkView arrays: (K,) channel counts, ETA inputs."""
     open_mask = chunk_of != _NO_CHUNK
@@ -245,12 +269,7 @@ def _phase_advance(
     if row["prof_t"].shape[-1] == 1:  # static path: the common case
         eff_bw, next_prof = row["bw"], xp.inf
     else:
-        prof_at = xp.sum(row["prof_t"] <= row["t"]) - 1
-        mult = row["prof_mult"][xp.maximum(prof_at, 0)]
-        eff_bw = row["bw"] * xp.where(prof_at >= 0, mult, 1.0)
-        next_prof = xp.min(
-            xp.where(row["prof_t"] > row["t"], row["prof_t"], xp.inf)
-        )
+        eff_bw, next_prof = _profile_bw(xp, row), _next_profile_step(xp, row)
     if coupled:
         pool = row["_pool_ovr"]
     else:
@@ -707,9 +726,7 @@ def _row_demand(row: dict):
     if row["prof_t"].shape[-1] == 1:
         eff_bw = row["bw"]
     else:
-        prof_at = xp.sum(row["prof_t"] <= row["t"]) - 1
-        mult = row["prof_mult"][xp.maximum(prof_at, 0)]
-        eff_bw = row["bw"] * xp.where(prof_at >= 0, mult, 1.0)
+        eff_bw = _profile_bw(xp, row)
     pool = kernels.disk_pool(
         ops, xp.sum(transferring), eff_bw, row["disk_rate"],
         row["sat_cc"], row["contention"],
@@ -729,9 +746,7 @@ def _row_horizon(row: dict, pool):
     if row["prof_t"].shape[-1] == 1:
         next_prof = xp.inf
     else:
-        next_prof = xp.min(
-            xp.where(row["prof_t"] > row["t"], row["prof_t"], xp.inf)
-        )
+        next_prof = _next_profile_step(xp, row)
     rates = kernels.waterfill(
         ops, xp.where(transferring, row["cap"], 0.0), pool
     )
@@ -1300,6 +1315,15 @@ class JaxFabricSimulation(FabricSimulation):
                 )
         stats["state_syncs"] += 1
 
+    def _profile_steps(self) -> int:
+        """Profile steps after t = 0 and no later than each row's finish
+        time, summed over the done rows of the synced host arrays (a
+        static row's one step sits at t = 0, pad steps at +inf)."""
+        if self.prof_t.shape[1] == 1:
+            return 0
+        hit = (self.prof_t > 0.0) & (self.prof_t <= self.finish_t[:, None])
+        return int(hit[self.done].sum())
+
     def _raise_err(self, err: np.ndarray) -> None:
         """Raise for the first row whose device ``err`` flag is set; the
         host arrays must be synced, the message reads ``t`` and
@@ -1383,6 +1407,12 @@ class JaxFabricSimulation(FabricSimulation):
         stats = {k: 0 for k in SYNC_STATS}
         stats["runs"] = 1
         stats["scenarios"] = self.S
+        # the channel axis as built for this run, against what its rows
+        # can ever hold
+        stats["channel_slots"] = self.S * self.C
+        stats["channel_slots_live"] = int(
+            np.minimum(self.cap_need, self.C).sum()
+        )
         # the flat file-size buffer is a jit-signature axis too — its raw
         # length is the batch's total file count, different for every
         # chunk, which made every chunk a fresh XLA compile. Zero-pad to
@@ -1468,11 +1498,14 @@ class JaxFabricSimulation(FabricSimulation):
                     )
                 if self._compaction_due():
                     sync()
+                    # compaction retires the done rows: count them now
+                    stats["profile_steps"] += self._profile_steps()
                     self._maybe_compact()
         finally:
             # the loop's end, or an exception leaving it: results and
             # error reports read the host arrays
             try:
                 sync()
+                stats["profile_steps"] += self._profile_steps()
             finally:
                 _merge_sync_stats(stats)

@@ -48,6 +48,18 @@ Counters other than the walls:
   ``replay_rounds``       rounds ended by a host ``_post`` replay
   ``post_row_replays``    parked rows replayed
   ``scenarios``/``runs``  rows and driver runs
+  ``profile_steps``       bandwidth-profile steps after t = 0 and no
+                          later than the row's finish time, over real
+                          rows; read from the whole-state syncs a run
+                          makes anyway (before a compaction, at its end);
+                          0 for static paths
+  ``channel_slots``       real rows times the padded channel axis C,
+                          counted once per driver run as the batch is
+                          built
+  ``channel_slots_live``  the most channels each of those rows can hold
+                          (its closed-form worst case, ``cap_need``),
+                          summed: ``1 - live / slots`` is the share of
+                          the channel axis no row can use
   ======================  =============================================
 
 Wall keys are float seconds and overlap freely (several prep/compute
@@ -90,6 +102,9 @@ SYNC_STATS = {
     "post_row_replays": 0,
     "scenarios": 0,
     "runs": 0,
+    "profile_steps": 0,
+    "channel_slots": 0,
+    "channel_slots_live": 0,
     "build_wall_s": 0.0,
     "compute_wall_s": 0.0,
     "upload_wall_s": 0.0,

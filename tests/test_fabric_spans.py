@@ -4,6 +4,7 @@
 into ``fabric.upload``, ``fabric.device`` and ``fabric.download`` inside
 the executor's ``fabric.run``, and ``iterations`` counts the device loop's
 iterations."""
+import functools
 import os
 import re
 import subprocess
@@ -25,6 +26,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROUND_SPANS = ("fabric.upload", "fabric.device", "fabric.download")
 #: (rows, C, K, P, B, T, Q): a small loop signature, cheap to compile
 SMALL_SIG = (16, 8, 4, 16, 1, 1, 1024)
+#: its profiled twin: bandwidth-profile width 16 on the profiled
+#: channel floor
+PROFILED_SIG = (16, 16, 4, 17, 16, 1, 1024)
 LOOP_SCOPES = (
     jax_backend.SCOPE_ADVANCE, jax_backend.SCOPE_COMPLETE,
     jax_backend.SCOPE_TICK, jax_backend.SCOPE_MOVE,
@@ -132,15 +136,16 @@ def test_profiler_trace_nests_round_spans_in_run(tmp_path):
     assert all(found.values()), found
 
 
-def _compiled_loop(coupled: bool):
+@functools.lru_cache(maxsize=None)
+def _compiled_loop(coupled: bool, sig=SMALL_SIG):
     dev = jax.devices()[0]
     with x64():
-        mut, const, qsizes = jax_backend.signature_shapes(SMALL_SIG, dev)
+        mut, const, qsizes = jax_backend.signature_shapes(sig, dev)
         if not coupled:
             return jax_backend._device_rounds.lower(
                 mut, const, qsizes, COMPACT_FLOOR
             ).compile()
-        rows, links, groups = SMALL_SIG[0], 4, 2
+        rows, links, groups = sig[0], 4, 2
         i64, f64 = jax.numpy.int64, jax.numpy.float64
         fab = {
             "gid": jax.ShapeDtypeStruct((rows,), i64),
@@ -158,12 +163,33 @@ def test_loop_phases_reach_the_compiled_op_names(coupled):
     """Each named phase of the loop body is a component of some op's
     ``op_name`` in the compiled HLO: that metadata, not the device
     trace's op events, ties a fusion to its phase."""
-    hlo = _compiled_loop(coupled).as_text()
-    parts = set()
-    for op_name in re.findall(r'op_name="([^"]*)"', hlo):
-        parts.update(op_name.split("/"))
+    parts = _op_name_parts(_compiled_loop(coupled))
     scopes = LOOP_SCOPES + ((jax_backend.SCOPE_WATERFILL,) if coupled
                             else ())
     assert set(scopes) <= parts, sorted(set(scopes) - parts)
     if not coupled:
         assert jax_backend.SCOPE_WATERFILL not in parts
+
+
+def _op_name_parts(compiled) -> set:
+    """The components of every ``op_name``; a scope opened inside a
+    vmapped function reads ``vmap(<scope>)`` there, and counts as
+    ``<scope>``."""
+    parts = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', compiled.as_text()):
+        parts.update(re.sub(r"^vmap\((.*)\)$", r"\1", part)
+                     for part in op_name.split("/"))
+    return parts
+
+
+@pytest.mark.parametrize("coupled", [False, True], ids=["grid", "coupled"])
+@pytest.mark.parametrize("profiled", [False, True],
+                         ids=["static", "profiled"])
+def test_profile_scope_only_in_profiled_programs(coupled, profiled):
+    """The profiled rows' capacity lookup and step horizon are the
+    ``profile_capacity`` scope of the compiled HLO; the static (B == 1)
+    programs have no such ops."""
+    sig = PROFILED_SIG if profiled else SMALL_SIG
+    parts = _op_name_parts(_compiled_loop(coupled, sig))
+    assert (jax_backend.SCOPE_PROFILE in parts) == profiled
+    assert jax_backend.SCOPE_ADVANCE in parts
