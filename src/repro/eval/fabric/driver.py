@@ -569,18 +569,12 @@ class FabricSimulation:
 
         self.cap_need = plan.cap_need.copy()
         # plan chunks pad the channel axis to the shape-hint floor: a few
-        # dead columns buy every cc<=PLAN_C_FLOOR chunk the SAME compiled
-        # C, and batches holding profiled rows share one (C, B=16)
-        # program family (see ScenarioPlan.shape_hints)
-        from .plan import (
-            PLAN_C_FLOOR,
-            PLAN_COMPACT_FLOOR,
-            PLAN_PROFILED_C_FLOOR,
-        )
+        # dead columns buy every cc<=PLAN_C_FLOOR chunk, profiled or not,
+        # the SAME compiled C; wider chunks pad to their capacity bucket
+        # (capacity_need; see ScenarioPlan.shape_hints)
+        from .plan import PLAN_C_FLOOR, PLAN_COMPACT_FLOOR
 
-        self._need_c_floor = (
-            PLAN_C_FLOOR if B == 1 else PLAN_PROFILED_C_FLOOR
-        )
+        self._need_c_floor = PLAN_C_FLOOR
         # all-static batches (baseline + candidate rows, no timelines)
         # drain chunk-at-a-time, so compaction stops at the plane floor:
         # the grid's narrow straggler rungs would only add device

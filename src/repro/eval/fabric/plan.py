@@ -73,14 +73,11 @@ PLAN_ALGORITHMS = frozenset(
 #: every plan chunk's worst-case channel axis up to this, merging the
 #: cc<=4 and cc<=8 rows into ONE compiled (C=8) program family — the pad
 #: is a few never-selected channel columns, while each extra C value is a
-#: full trace+compile (or cache read) per rows-rung per process
+#: full trace+compile (or cache read) per rows-rung per process. Batches
+#: holding profiled rows take the same floor: a chunk whose rows need
+#: more channels pads to their capacity bucket (16, 32, ...), as a static
+#: chunk does
 PLAN_C_FLOOR = 8
-
-#: channel floor for batches holding profiled (time-varying) rows: all
-#: profiled rows form a single shape-hint group regardless of cc, so
-#: their chunks pad to one (C=16, B=16) program family instead of a B=16
-#: twin of every capacity bucket
-PLAN_PROFILED_C_FLOOR = 16
 
 #: compaction floor for all-static candidate-plane batches (every row
 #: ``kind <= _KIND_STATIC``, no timelines): plane rows of one context
@@ -292,11 +289,14 @@ class ScenarioPlan:
           so merging the tiny-cc buckets into one group costs nothing
           and halves the distinct compiled ``C`` values;
         * rows on profiled (time-varying) networks form ONE trailing
-          group regardless of capacity (the driver floors their channel
-          axis at :data:`PLAN_PROFILED_C_FLOOR`): one scattered profiled
-          row widens its whole chunk's bandwidth-profile axis to the
-          B=16 pad, so letting the cost sort deal them everywhere used
-          to mint a ``B=16`` twin of nearly every ``(rows, C)`` program.
+          group regardless of capacity: one scattered profiled row
+          widens its whole chunk's bandwidth-profile axis to the B=16
+          pad, so letting the cost sort deal them everywhere used to
+          mint a ``B=16`` twin of nearly every ``(rows, C)`` program.
+          The driver still pads a profiled chunk's channel axis to what
+          its rows need, floored at :data:`PLAN_C_FLOOR`, so profiled
+          rows of two capacity buckets in one process compile a
+          ``(C, B=16)`` program of each.
         """
         plens = np.array(
             [

@@ -26,9 +26,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROUND_SPANS = ("fabric.upload", "fabric.device", "fabric.download")
 #: (rows, C, K, P, B, T, Q): a small loop signature, cheap to compile
 SMALL_SIG = (16, 8, 4, 16, 1, 1, 1024)
-#: its profiled twin: bandwidth-profile width 16 on the profiled
-#: channel floor
-PROFILED_SIG = (16, 16, 4, 17, 16, 1, 1024)
+#: its profiled twin: bandwidth-profile width 16 on the same channel
+#: floor, the shape the cross-traffic cell runs
+PROFILED_SIG = (16, 8, 4, 16, 16, 1, 1024)
 LOOP_SCOPES = (
     jax_backend.SCOPE_ADVANCE, jax_backend.SCOPE_COMPLETE,
     jax_backend.SCOPE_TICK, jax_backend.SCOPE_MOVE,

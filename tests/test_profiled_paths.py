@@ -6,6 +6,7 @@ transfers. Every row agrees with the plain reference
 (``bench/reference/transfer.py``) and with the event simulator, and the
 loop's counters say how many steps fell inside the transfers and how
 much of the channel axis is padding."""
+import dataclasses
 import json
 import os
 import sys
@@ -15,6 +16,7 @@ import pytest
 
 from repro.eval.difftest import DEFAULT_RTOL
 from repro.eval.fabric import jax_backend
+from repro.eval.fabric import plan as plan_module
 from repro.eval.fabric.jax_backend import JaxFabricSimulation
 from repro.eval.fabric.plan import build_plan
 from repro.eval.runner import run_matrix
@@ -165,20 +167,52 @@ def test_profile_steps_read_zero_on_static_paths():
     assert np.all(rel_gaps(got, ref) <= REFERENCE_RTOL)
 
 
-@pytest.mark.parametrize("profiled", [True, False],
-                         ids=["profiled", "static"])
-def test_channel_slots_match_the_batch_shape(profiled):
-    plan = build_plan(_sweep(profiled).scenarios)
+def _widened(scenarios: list) -> list:
+    """The scenarios with the first MC row at maxCC 16: its ``cap_need``
+    is 16, above the plan's channel floor."""
+    i = next(i for i, sc in enumerate(scenarios) if sc.algorithm == "mc")
+    return scenarios[:i] + [
+        dataclasses.replace(scenarios[i], max_cc=16)
+    ] + scenarios[i + 1:]
+
+
+@pytest.mark.parametrize("profiled, widen, want_c", [
+    (True, False, 8), (False, False, 8), (True, True, 16),
+], ids=["profiled", "static", "profiled-wide"])
+def test_channel_slots_match_the_batch_shape(profiled, widen, want_c):
+    scenarios = _sweep(profiled).scenarios
+    if widen:
+        scenarios = _widened(scenarios)
+    plan = build_plan(scenarios)
+    assert plan.cap_need.max() == want_c
     sim = JaxFabricSimulation(None, plan=plan)
     jax_backend.reset_sync_stats()
     sim.run()
     stats = jax_backend.SYNC_STATS
-    # the profiled rows' batch pads its channel axis to the profiled
-    # floor, static rows at maxCC 8 to the plan's
-    assert sim.C == (16 if profiled else 8)
+    # profiled and static rows at maxCC 8 pad their channel axis to the
+    # plan's floor; a batch with a row that needs more pads to its
+    # capacity bucket and still runs without a host replay
+    assert sim.C == want_c
+    assert stats["replay_rounds"] == stats["post_row_replays"] == 0
     assert stats["runs"] == 1
     assert stats["channel_slots"] == plan.n_rows * sim.C
     assert stats["channel_slots_live"] == int(
         np.minimum(plan.cap_need, sim.C).sum()
     )
     assert 0 < stats["channel_slots_live"] <= stats["channel_slots"]
+
+
+def test_channel_pad_changes_no_answer(answers, monkeypatch):
+    """The profiled smoke sweep at a channel floor of 16 gives the same
+    answers, in the same loop iterations, as at the plan's floor of 8:
+    the padded columns are never selected."""
+    assert plan_module.PLAN_C_FLOOR == 8
+    monkeypatch.setattr(plan_module, "PLAN_C_FLOOR", 16)
+    jax_backend.reset_sync_stats()
+    wide = answers["sweep"].run()
+    stats = jax_backend.SYNC_STATS
+    narrow = answers["stats"]
+    assert np.array_equal(wide, answers["jax"])
+    assert stats["iterations"] == narrow["iterations"]
+    assert stats["channel_slots_live"] == narrow["channel_slots_live"]
+    assert stats["channel_slots"] == 2 * narrow["channel_slots"]
