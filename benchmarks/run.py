@@ -3,18 +3,10 @@ TPU-adaptation benches. Prints ``name,us_per_call,derived`` CSV rows and a
 paper-claim validation summary.
 
     PYTHONPATH=src python -m benchmarks.run [--only fig1]
-    PYTHONPATH=src python -m benchmarks.run --only eval_matrix \
-        --bench-json BENCH_eval_matrix.json
-
-``--bench-json`` writes the eval-matrix perf trajectory (scenarios/sec per
-backend, wall times, grid size, jax/numpy crossover) so future PRs have a
-baseline to beat; the checked-in ``BENCH_eval_matrix.json`` is the first
-point of that trajectory.
 """
 from __future__ import annotations
 
 import argparse
-import json
 import sys
 import time
 
@@ -26,7 +18,6 @@ MODULES = [
     ("fig7", "benchmarks.fig7_dataset_size"),
     ("fig9_11", "benchmarks.fig9_10_11_datasets"),
     ("fig12_13", "benchmarks.fig12_fig13_promc_lan"),
-    ("eval_matrix", "benchmarks.eval_matrix_bench"),
     ("grad_sync", "benchmarks.grad_sync_bench"),
     ("checkpoint", "benchmarks.checkpoint_bench"),
     ("kernels", "benchmarks.kernel_bench"),
@@ -37,11 +28,6 @@ MODULES = [
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="substring filter on module")
-    ap.add_argument(
-        "--bench-json", default=None, metavar="PATH",
-        help="write the eval_matrix perf snapshot to PATH "
-        "(runs the eval_matrix bench if --only filtered it out)",
-    )
     args = ap.parse_args()
 
     # the persistent compilation cache: JAX_COMPILATION_CACHE_DIR, else
@@ -57,8 +43,7 @@ def main() -> int:
     t_start = time.time()
     for key, modname in MODULES:
         if args.only and args.only not in key:
-            if not (args.bench_json and key == "eval_matrix"):
-                continue
+            continue
         t0 = time.time()
         mod = __import__(modname, fromlist=["run"])
         try:
@@ -71,18 +56,6 @@ def main() -> int:
             print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']}",
                   flush=True)
         print(f"# {key} done in {time.time()-t0:.1f}s", file=sys.stderr)
-
-    if args.bench_json:
-        from benchmarks import eval_matrix_bench
-
-        if eval_matrix_bench.LAST_SNAPSHOT is None:
-            print("# bench-json: eval_matrix did not produce a snapshot",
-                  file=sys.stderr)
-        else:
-            with open(args.bench_json, "w") as f:
-                json.dump(eval_matrix_bench.LAST_SNAPSHOT, f, indent=1)
-                f.write("\n")
-            print(f"# wrote {args.bench_json}", file=sys.stderr)
 
     print(claims.report())
     print(f"# total {time.time()-t_start:.1f}s", file=sys.stderr)

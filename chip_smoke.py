@@ -11,7 +11,7 @@ a warm repeat in the same process (steady) — and each held to the event
 reference at the difftest's 2% bar with zero parked-row replays.
 
 ``--four-chips``: the 64-candidate oracle plane over the full grid
-(``tune.oracle_search``, ~16.7k rows) through the async executor on
+(``tune.oracle_search``, ~16.7k rows) through the pipelined executor on
 ``jax.devices()[:4]`` and on ``jax.devices()[:1]`` in the same process;
 per-row throughputs must be identical, and every one of the four chips
 must have run chunks.

@@ -1,15 +1,11 @@
 """Overlap-pipelined, multi-device chunk executor for the batched sweeps.
 
-``runner.run_built`` used to execute chunks strictly serially: build one
-chunk's Simulations on the host (scenario assembly, ``build_files``,
-padding/bucketing), run the driver, block on the download, repeat — the
-host sat idle while the device ran and vice versa. This module replaces
-that loop with a small pipeline:
+``runner.run_built`` and ``runner.run_plan`` hand their chunks to
+:func:`execute_chunks`, which runs them through a small pipeline:
 
-  * one **prep thread** walks the chunks in order, builds each chunk's
-    Simulations and driver, submits its canonical-signature ladder to
-    its device's background AOT warm thread (jax), and hands the ready
-    driver to a
+  * **prep threads** walk the chunks in order, build each chunk's
+    driver, submit its canonical-signature ladder to its device's
+    background AOT warm thread (jax), and hand the ready driver to a
     bounded per-device queue — explicit double-buffered staging: while
     device ``d`` computes chunk ``j``, chunk ``j + n_devices`` is already
     built and waiting in ``d``'s queue, and the chunk after that is being
@@ -24,20 +20,12 @@ that loop with a small pipeline:
     ``--xla_force_host_platform_device_count=N``, as ``launch/dryrun.py``
     does).
 
-The queue bound is the backpressure: at most ``queue_depth`` staged
-chunks per device plus the one being built, so peak host memory stays a
-small constant multiple of one chunk — not the whole sweep.
-
-``REPRO_FABRIC_EXECUTOR=serial`` is the escape hatch: it restores the
-exact pre-pipeline execution path (same thread, same loop, no device
-pinning, no AOT warm, donation off unless forced) for debugging — a
-traceback then points at a plain call stack, and buffer donation cannot
-be a variable. ``REPRO_FABRIC_EXECUTOR_DEPTH`` overrides the staging
-depth.
+The queue bound is the backpressure: at most :data:`DEFAULT_QUEUE_DEPTH`
+staged chunks per device plus the one being built, so peak host memory
+stays a small constant multiple of one chunk — not the whole sweep.
 
 Any worker/prep exception cancels the pipeline (remaining chunks are
-discarded) and re-raises in the caller, so failure behaviour matches the
-serial loop's fail-fast semantics.
+discarded) and re-raises in the caller.
 """
 from __future__ import annotations
 
@@ -49,24 +37,9 @@ from typing import Callable, List, Optional, Sequence
 
 from .stats import span
 
-#: recognised ``REPRO_FABRIC_EXECUTOR`` values
-EXECUTOR_MODES = ("serial", "async")
-
 #: staged (built-but-not-running) chunks per device: 1 is classic double
 #: buffering — one chunk in flight, one staged, one being built
 DEFAULT_QUEUE_DEPTH = 1
-
-
-def executor_mode(override: Optional[str] = None) -> str:
-    """Resolve the executor mode: explicit ``override`` (a run_built /
-    run_matrix kwarg or CLI flag) wins, then ``REPRO_FABRIC_EXECUTOR``,
-    then the async default."""
-    mode = override or os.environ.get("REPRO_FABRIC_EXECUTOR") or "async"
-    if mode not in EXECUTOR_MODES:
-        raise ValueError(
-            f"unknown executor mode {mode!r}; options: {EXECUTOR_MODES}"
-        )
-    return mode
 
 
 #: device list set by :func:`pinned_devices` (None: all of jax.devices())
@@ -109,14 +82,6 @@ def backend_devices(cls) -> list:
     return [None]
 
 
-def _queue_depth(depth: Optional[int]) -> int:
-    if depth is None:
-        depth = int(
-            os.environ.get("REPRO_FABRIC_EXECUTOR_DEPTH", DEFAULT_QUEUE_DEPTH)
-        )
-    return max(1, depth)
-
-
 def _warm_chunk(driver) -> None:
     """AOT-compile the chunk's signature ladder (initial shape + every
     compaction rung) so the compute worker finds ready executables."""
@@ -125,9 +90,7 @@ def _warm_chunk(driver) -> None:
 
     floor = driver.compact_floor()
     for rung in signature_ladder(canonical_signature(driver), floor):
-        jax_backend.warm_signature(
-            rung, device=driver.device, donate=driver.donate, floor=floor
-        )
+        jax_backend.warm_signature(rung, device=driver.device, floor=floor)
 
 
 def _warm_loop(warm_q: "queue.Queue", stop: Optional[threading.Event],
@@ -162,8 +125,6 @@ def execute_chunks(
     builders: Optional[Sequence[Callable]],
     names: Optional[Sequence[str]],
     results: List,
-    mode: Optional[str] = None,
-    queue_depth: Optional[int] = None,
     *,
     make_chunk: Optional[Callable] = None,
     prep_workers: Optional[int] = None,
@@ -176,20 +137,18 @@ def execute_chunks(
     path slices a ``ScenarioPlan``); the default builds ``Simulation``
     objects through ``builders``/``names`` — the legacy object path.
 
-    ``mode="serial"`` runs the historical strictly-serial loop; the
-    default async pipeline overlaps host prep, device compute, and AOT
-    warming, sharding chunks across devices round-robin. ``prep_workers``
+    The pipeline overlaps host prep, device compute, and AOT warming,
+    sharding chunks across devices round-robin. ``prep_workers``
     (default 1) parallelizes chunk prep — only raise it when
     ``make_chunk`` is thread-safe, as plan slicing is and the legacy
     builder chain (shared file-cache hits aside) generally is not
     guaranteed to be.
 
     Chunk build and driver-run wall time accumulate into the shared
-    ``stats.SYNC_STATS`` wall keys in every mode (spans ``fabric.build``
-    and ``fabric.run``), so the prep-vs-compute breakdown
-    (``runner --verbose``) measures the host build tax.
+    ``stats.SYNC_STATS`` wall keys (spans ``fabric.build`` and
+    ``fabric.run``), so the prep-vs-compute breakdown (``runner
+    --verbose``) measures the host build tax.
     """
-    mode = executor_mode(mode)
     parts = [list(p) for p in parts]
     placed = getattr(cls, "supports_device_placement", False)
 
@@ -202,14 +161,7 @@ def execute_chunks(
             kwargs = {"device": dev} if placed else {}
             return cls(sims, names=[names[i] for i in part], **kwargs)
 
-    if mode == "serial" or len(parts) <= 0:
-        for part in parts:
-            with span("fabric.build", "build_wall_s"):
-                driver = make_chunk(part, None)
-            with span("fabric.run", "compute_wall_s"):
-                out = driver.run()
-            for i, res in zip(part, out):
-                results[i] = res
+    if not parts:
         return results
 
     devices = backend_devices(cls)
@@ -218,9 +170,8 @@ def execute_chunks(
     # (non-executor) runs of the same shapes
     if len(devices) == 1:
         devices = [None]
-    depth = _queue_depth(queue_depth)
     queues: List[queue.Queue] = [
-        queue.Queue(maxsize=depth) for _ in devices
+        queue.Queue(maxsize=DEFAULT_QUEUE_DEPTH) for _ in devices
     ]
     stop = threading.Event()
     errors: List[BaseException] = []
@@ -312,8 +263,7 @@ def execute_chunks(
         t.start()
     for t in prep_threads + compute_threads:
         t.start()
-    # sentinels flow only after every prep worker is finished (with one
-    # ordered prep thread they used to ride its ``finally``)
+    # sentinels flow only after every prep worker is finished
     for t in prep_threads:
         t.join()
     for q in queues:

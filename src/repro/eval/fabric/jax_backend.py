@@ -41,19 +41,18 @@ Numerics run in float64 via the scoped :func:`jaxenv.x64` context
 (``jax.enable_x64``, never the global flag: the rest of the repo traces
 in f32).
 
-Execution-loop structure (the overlap-pipelined executor rides on it):
+Execution-loop structure (one path: every run, direct or through the
+overlap-pipelined executor, calls the same two jitted programs):
 
   * the jit boundary takes *(mutable, const, qsizes)* instead of one
     merged state dict, and only the mutable half is carried through the
     ``while_loop`` — the read-only tables are closed over as loop
     invariants, so the carry's double buffer covers state that actually
     changes, not the decision tables;
-  * the mutable half is **donated** (``donate_argnums=0``) whenever
-    :func:`donation_enabled` says so (default: on under the async
-    executor, forced via ``REPRO_FABRIC_DONATE``), so steady-state sweeps
-    update device buffers in place instead of allocating a second copy.
-    Donated buffers are dead after the call — the driver never touches a
-    donated array again;
+  * the mutable half is **donated** (``donate_argnums=0``), so
+    steady-state sweeps update device buffers in place instead of
+    allocating a second copy. Donated buffers are dead after the call —
+    the driver never touches a donated array again;
   * the carry stays **resident** on the device between rounds: one
     round's output state is the next round's ``mut``, and the host reads
     only the ``done``/``stall``/``err`` flags and the iteration count.
@@ -65,12 +64,13 @@ Execution-loop structure (the overlap-pipelined executor rides on it):
   * :func:`warm_signature` AOT-compiles (``jit(...).lower().compile()``)
     the loop for a canonical :func:`bucketing.canonical_signature` before
     the first chunk needs it, taking the ~1 s/signature Python retrace
-    off the critical path. ``SYNC_STATS`` merges are per-run atomic so
-    interleaved chunks report the same totals as serial execution.
+    off the critical path; the XLA persistent cache
+    (:func:`jaxenv.arm_compile_cache`) serves the backend compile across
+    processes. ``SYNC_STATS`` merges are per-run atomic so interleaved
+    chunks report the same totals as one run after another.
 """
 from __future__ import annotations
 
-import os
 import threading
 from typing import List, Optional, Sequence, Tuple
 
@@ -91,7 +91,7 @@ from .driver import (
     KIND_SC,
     FabricSimulation,
 )
-from .jaxenv import cache_dir, x64
+from .jaxenv import x64
 from .shim import jax_ops
 
 _ERR_NONE, _ERR_MAXTIME, _ERR_STRANDED = 0, 1, 2
@@ -127,22 +127,6 @@ from .stats import (  # noqa: E402,F401  (re-exported API)
     reset_sync_stats,
     span,
 )
-
-
-def donation_enabled(default: Optional[bool] = None) -> bool:
-    """Resolve the buffer-donation toggle: ``REPRO_FABRIC_DONATE`` wins,
-    else ``default`` (a driver kwarg), else on exactly when the async
-    executor is active — ``REPRO_FABRIC_EXECUTOR=serial`` preserves the
-    undonated pre-executor execution path byte for byte. Donated programs
-    go through the persistent compilation cache like any other."""
-    env = os.environ.get("REPRO_FABRIC_DONATE")
-    if env is not None:
-        return env.strip().lower() not in ("0", "false", "off", "no", "")
-    if default is not None:
-        return bool(default)
-    from .executor import executor_mode
-
-    return executor_mode() == "async"
 
 
 #: state arrays the device sweep may mutate (host <-> device sync set)
@@ -607,7 +591,7 @@ def _device_rounds_fn(mut: dict, const: dict, qsizes, compact_floor: int):
     batch-level ``lax.cond`` — completions, ProMC ticks, and fired moves
     are sparse across sweeps, so most iterations pay phase A alone.
 
-    ``mut`` is the carried (and donatable) half; ``const`` the per-batch
+    ``mut`` is the carried (and donated) half; ``const`` the per-batch
     read-only tables, merged into the phase row-dicts each iteration and
     stripped before the carry closes. ``compact_floor`` is the *static*
     per-batch compaction floor (part of the program identity): it decides
@@ -693,16 +677,13 @@ def _device_rounds_fn(mut: dict, const: dict, qsizes, compact_floor: int):
     return state, iters
 
 
-#: the undonated loop (exact pre-executor semantics: inputs stay live).
-#: ``compact_floor`` is static: two batches with identical shapes but
-#: different floors are different programs (the early-exit clause folds
-#: at trace time)
-_device_rounds = jax.jit(_device_rounds_fn, static_argnums=3)
-#: the donated twin: the mutable carry updates in place, halving the
-#: loop's peak device footprint. The driver passes each round's output
-#: (or a fresh upload) as the next input, so donated inputs are never
-#: read again.
-_device_rounds_donated = jax.jit(
+#: the device loop. The mutable carry is donated and updates in place,
+#: halving the loop's peak device footprint: the driver passes each
+#: round's output (or a fresh upload) as the next input, so donated
+#: inputs are never read again. ``compact_floor`` is static: two batches
+#: with identical shapes but different floors are different programs
+#: (the early-exit clause folds at trace time)
+_device_rounds = jax.jit(
     _device_rounds_fn, donate_argnums=0, static_argnums=3
 )
 
@@ -760,7 +741,7 @@ def _row_horizon(row: dict, pool):
 def _device_rounds_coupled_fn(
     mut: dict, const: dict, qsizes, fab: dict, compact_floor: int
 ):
-    """The shared-fabric twin of :func:`_device_rounds_fn`: identical
+    """The shared-fabric variant of :func:`_device_rounds_fn`: identical
     vmapped phases, but every sweep starts with one cross-row coupling
     step — per-row demands (vmapped), one batch ``waterfill_coupled``
     over the (links x rows) membership table, per-row horizons under the
@@ -857,13 +838,11 @@ def _device_rounds_coupled_fn(
     return state, iters
 
 
-#: the coupled loop and its donated twin. ``fab`` rides the jit
-#: signature through its array shapes (L, G, rows) — bucketed to the
-#: pow2 ladder by ``_upload_fabric`` so the program count stays bounded.
+#: the coupled loop, its carry donated like :data:`_device_rounds`'s.
+#: ``fab`` rides the jit signature through its array shapes (L, G,
+#: rows) — bucketed to the pow2 ladder by ``_upload_fabric`` so the
+#: program count stays bounded.
 _device_rounds_coupled = jax.jit(
-    _device_rounds_coupled_fn, static_argnums=4
-)
-_device_rounds_coupled_donated = jax.jit(
     _device_rounds_coupled_fn, donate_argnums=0, static_argnums=4
 )
 
@@ -951,115 +930,26 @@ def signature_shapes(
 
 
 _AOT_LOCK = threading.Lock()
-#: ``(sig, device, donate, floor) -> jax.stages.Compiled`` (``None``
+#: ``(sig, device, floor) -> jax.stages.Compiled`` (``None``
 #: records a warm that raised; the error reaches the warm's caller)
 _AOT_CACHE: dict = {}
 #: in-flight warms: waiters block on the event instead of re-compiling
 _AOT_PENDING: dict = {}
 
 
-# ------------------------------------------------------------------ #
-# Trace cache: serialized jax.export blobs alongside the XLA cache
-# ------------------------------------------------------------------ #
-#
-# The persistent XLA cache only skips the *backend compile*; every fresh
-# process still pays ~1 s of Python trace + StableHLO lowering per
-# program before the cache can even be consulted. ``jax.export``
-# captures exactly that lowered artifact, so warm processes deserialize
-# the StableHLO from disk (~15 ms) and hand it straight to XLA — whose
-# persistent cache then returns the executable — instead of re-tracing.
-# Cold and warm paths both compile the *exported* call so they share one
-# HLO identity (and one XLA cache entry) per program.
-#
-# Blobs are keyed on the backend platform, the signature and a digest of
-# the jax version and every source file under this package (controllers/
-# and kernels/ included) — any edit to the traced code (or the constants
-# it closes over) invalidates the whole trace cache. A blob exported for
-# another platform is a miss. Donated programs are excluded (donation
-# metadata does not survive the export round trip); they compile from
-# the jit twin, which the persistent XLA cache serves directly.
-
-_EXPORT_DIGEST: Optional[str] = None
+def _aot_key(sig, device, floor):
+    return (tuple(int(x) for x in sig), device, int(floor))
 
 
-def _export_digest() -> str:
-    """Digest of everything the device-loop trace can depend on: the jax
-    version plus the bytes of every ``.py`` file under this package."""
-    global _EXPORT_DIGEST
-    if _EXPORT_DIGEST is None:
-        import hashlib
-
-        h = hashlib.sha256(jax.__version__.encode())
-        pkg = os.path.dirname(os.path.abspath(__file__))
-        for root, dirs, files in os.walk(pkg):
-            dirs.sort()
-            for name in sorted(files):
-                if name.endswith(".py"):
-                    path = os.path.join(root, name)
-                    with open(path, "rb") as f:
-                        h.update(os.path.relpath(path, pkg).encode())
-                        h.update(f.read())
-        _EXPORT_DIGEST = h.hexdigest()[:32]
-    return _EXPORT_DIGEST
-
-
-def _export_path(sig, floor: int) -> Optional[str]:
-    """Blob path for one signature on the current backend, or None when
-    no persistent cache directory is configured (no point caching traces
-    the process can't amortize across runs)."""
-    base = cache_dir()
-    if not base:
-        return None
-    name = "rounds-{}-{}-f{}-{}.stablehlo".format(
-        jax.default_backend(), "x".join(str(int(x)) for x in sig),
-        int(floor), _export_digest(),
-    )
-    return os.path.join(base, "exports", name)
-
-
-def _exported_rounds(sig, shapes, floor: int):
-    """The exported device loop for ``sig``: deserialized from the blob
-    cache when present, else traced now and written back (best effort).
-    ``shapes`` must be the device-free avals — sharding is applied later
-    at compile time, keeping one blob valid for every device."""
-    from jax import export as jax_export
-
-    path = _export_path(sig, floor)
-    if path is not None and os.path.exists(path):
-        with open(path, "rb") as f:
-            exp = jax_export.deserialize(f.read())
-        if jax.default_backend() in exp.platforms:
-            return exp
-    exp = jax_export.export(_device_rounds)(*shapes, int(floor))
-    if path is not None:
-        try:
-            os.makedirs(os.path.dirname(path), exist_ok=True)
-            tmp = "{}.tmp.{}".format(path, os.getpid())
-            with open(tmp, "wb") as f:
-                f.write(exp.serialize())
-            os.replace(tmp, path)
-        except OSError:
-            pass  # a read-only cache dir only costs the next process a trace
-    return exp
-
-
-def _aot_key(sig, device, donate, floor):
-    return (tuple(int(x) for x in sig), device, bool(donate), int(floor))
-
-
-def warm_signature(
-    sig, device=None, donate: Optional[bool] = None,
-    floor: int = COMPACT_FLOOR,
-) -> bool:
+def warm_signature(sig, device=None, floor: int = COMPACT_FLOOR) -> bool:
     """AOT-compile the device loop for one canonical signature (exactly
-    once per ``(sig, device, donate)`` process-wide; concurrent callers
+    once per ``(sig, device, floor)`` process-wide; concurrent callers
     wait). Returns True if this call did the compile. The executor warms
     each chunk's signature — and its compaction rungs — from a background
     thread while earlier chunks compute, so by the time a chunk reaches
     the device its executable already exists and the ~1 s/signature
     Python retrace never lands on the critical path."""
-    donate = donation_enabled(donate)
-    key = _aot_key(sig, device, donate, floor)
+    key = _aot_key(sig, device, floor)
     with _AOT_LOCK:
         if key in _AOT_CACHE:
             return False
@@ -1078,20 +968,9 @@ def warm_signature(
         # x64 is thread-local: the warm thread needs its own context so
         # the traced avals match the runtime's f64 uploads
         with x64():
-            if not donate and cache_dir():
-                exp = _exported_rounds(
-                    sig, signature_shapes(sig, None), floor
-                )
-                compiled = (
-                    jax.jit(exp.call)
-                    .lower(*signature_shapes(sig, device))
-                    .compile()
-                )
-            else:
-                fn = _device_rounds_donated if donate else _device_rounds
-                compiled = fn.lower(
-                    *signature_shapes(sig, device), int(floor)
-                ).compile()
+            compiled = _device_rounds.lower(
+                *signature_shapes(sig, device), int(floor)
+            ).compile()
     finally:
         with _AOT_LOCK:
             _AOT_CACHE[key] = compiled
@@ -1100,11 +979,11 @@ def warm_signature(
     return compiled is not None
 
 
-def _aot_lookup(sig, device, donate, floor):
+def _aot_lookup(sig, device, floor):
     """The compiled executable for a signature, waiting out an in-flight
     warm (the warm thread is already doing the same compile the jit
     fallback would pay); None if never warmed or the warm failed."""
-    key = _aot_key(sig, device, donate, floor)
+    key = _aot_key(sig, device, floor)
     with _AOT_LOCK:
         exe = _AOT_CACHE.get(key)
         ev = _AOT_PENDING.get(key)
@@ -1124,16 +1003,14 @@ def reset_aot_cache() -> None:
 
 def compiled_program_count() -> int:
     """Compiled executables for the device loop across all entry points:
-    the undonated jit, the donated twin, and the AOT warm cache — the
+    the AOT warm cache and the two jit caches (uncoupled, coupled) — the
     bench's compile-tax telemetry and the bucketing tests count this."""
     with _AOT_LOCK:
         aot = sum(1 for v in _AOT_CACHE.values() if v is not None)
     return (
         aot
         + _device_rounds._cache_size()
-        + _device_rounds_donated._cache_size()
         + _device_rounds_coupled._cache_size()
-        + _device_rounds_coupled_donated._cache_size()
     )
 
 
@@ -1152,7 +1029,6 @@ class JaxFabricSimulation(FabricSimulation):
     ``device`` pins every upload (and the AOT executable) to one
     ``jax.Device`` — the executor round-robins chunks across
     ``jax.devices()`` this way; None uses the default placement.
-    ``donate`` overrides :func:`donation_enabled` for this batch.
     """
 
     #: the executor passes ``device=`` only to drivers that advertise it
@@ -1164,12 +1040,10 @@ class JaxFabricSimulation(FabricSimulation):
         names: Optional[Sequence[str]] = None,
         *,
         device=None,
-        donate: Optional[bool] = None,
         **kwargs,
     ):
         super().__init__(sims, names=names, **kwargs)
         self.device = device
-        self.donate = donation_enabled(donate)
 
     # -------------------------------------------------------------- #
 
@@ -1272,28 +1146,22 @@ class JaxFabricSimulation(FabricSimulation):
 
     def _device_call(self, mut: dict, const: dict, qsizes):
         """One device round through the best available executable: the
-        AOT-warmed one when the executor pre-built it, else the jit twin
-        matching this batch's donation mode.
+        AOT-warmed one when the executor pre-built it, else the jit.
 
         Coupled batches never consult the AOT cache — the executor warms
         *uncoupled* signatures, and a shape-compatible uncoupled
         executable would silently run the wrong physics — they go
-        straight to the coupled jit twins.
+        straight to the coupled jit.
         """
         floor = self.compact_floor()
         if self.coupled:
-            fn = (
-                _device_rounds_coupled_donated if self.donate
-                else _device_rounds_coupled
+            return _device_rounds_coupled(
+                mut, const, qsizes, self._fab_dev, floor
             )
-            return fn(mut, const, qsizes, self._fab_dev, floor)
-        exe = _aot_lookup(
-            self._rounds_signature(), self.device, self.donate, floor
-        )
+        exe = _aot_lookup(self._rounds_signature(), self.device, floor)
         if exe is not None:
             return exe(mut, const, qsizes)
-        fn = _device_rounds_donated if self.donate else _device_rounds
-        return fn(mut, const, qsizes, floor)
+        return _device_rounds(mut, const, qsizes, floor)
 
     def _sync_host(self, state: dict, stats: dict) -> None:
         """Bring the host arrays up to date from a round's device state,
@@ -1402,8 +1270,8 @@ class JaxFabricSimulation(FabricSimulation):
         # end: under the pipelined executor several batches drive
         # concurrently, and per-increment writes to the module-global
         # counters would interleave (same totals, but torn reads for any
-        # observer); one locked merge per run keeps SYNC_STATS exactly
-        # serial-equivalent
+        # observer); one locked merge per run keeps SYNC_STATS equal to
+        # the same runs made one after another
         stats = {k: 0 for k in SYNC_STATS}
         stats["runs"] = 1
         stats["scenarios"] = self.S

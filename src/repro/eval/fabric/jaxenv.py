@@ -10,7 +10,7 @@ Two things live here:
     included) enters it itself.
   * :func:`arm_compile_cache` — JAX's persistent compilation cache for
     the command-line entry points (runner, difftest, ``chip_smoke.py``,
-    ``benchmarks.run``, ``benchmarks.mega_sweep``). Where
+    ``benchmarks.run``). Where
     ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and that
     directory is the cache; otherwise the cache lives at the fixed path
     ``<checkout>/.jax_cache`` (the path is part of the cache key, so it
@@ -39,13 +39,6 @@ def x64():
     import jax
 
     return jax.enable_x64(True)
-
-
-def cache_dir():
-    """The persistent compilation cache directory in effect, or None."""
-    import jax
-
-    return jax.config.jax_compilation_cache_dir or None
 
 
 def arm_compile_cache() -> str:

@@ -37,7 +37,7 @@ from typing import Dict, Iterable, List, Optional, Sequence
 from repro.core.simulator import SimResult, Simulation
 
 from .fabric.bucketing import bucket, chunk_spans
-from .fabric.executor import EXECUTOR_MODES, execute_chunks
+from .fabric.executor import execute_chunks
 from .scenarios import (
     Scenario,
     build_files,
@@ -207,7 +207,6 @@ def run_built(
     backend: str = "numpy",
     chunk_size: Optional[int] = DEFAULT_CHUNK_SIZE,
     hints: Optional[Sequence[int]] = None,
-    executor: Optional[str] = None,
     fabrics: Optional[Sequence] = None,
 ) -> List[SimResult]:
     """Chunked batched execution of *lazily built* Simulations.
@@ -234,13 +233,11 @@ def run_built(
     chunk spans are cut power-of-two-aligned so live rows fill the padded
     device shape instead of sweeping dead pad width.
 
-    ``executor`` picks the chunk execution strategy (see
-    :mod:`repro.eval.fabric.executor`): the default async pipeline
-    overlaps next-chunk host prep and AOT warm-compiles with in-flight
-    device compute and round-robins chunks across devices;
-    ``"serial"`` (or ``REPRO_FABRIC_EXECUTOR=serial``) restores the
-    historical strictly-serial loop. Results are in input order and
-    per-row outputs are identical under either mode — scenarios never
+    Chunks run through the pipelined executor
+    (:mod:`repro.eval.fabric.executor`), which overlaps next-chunk host
+    prep and AOT warm-compiles with in-flight device compute and
+    round-robins chunks across devices. Results are in input order, and
+    per-row outputs do not depend on chunking — scenarios never
     interact.
     """
     backend = _resolve_backend(backend)
@@ -283,10 +280,7 @@ def run_built(
             order[lo:hi]
             for lo, hi in chunk_spans(len(order), size, pad_aligned=aligned)
         ]
-    execute_chunks(
-        cls, parts, builders, names, results, mode=executor,
-        make_chunk=make_chunk,
-    )
+    execute_chunks(cls, parts, builders, names, results, make_chunk=make_chunk)
     return results  # type: ignore[return-value]
 
 
@@ -294,7 +288,6 @@ def run_plan(
     plan,
     backend: str = "numpy",
     chunk_size: Optional[int] = DEFAULT_CHUNK_SIZE,
-    executor: Optional[str] = None,
 ) -> List[SimResult]:
     """Chunked batched execution of a columnar :class:`ScenarioPlan`.
 
@@ -357,7 +350,7 @@ def run_plan(
         return drv
 
     execute_chunks(
-        cls, parts, None, None, results, mode=executor,
+        cls, parts, None, None, results,
         make_chunk=make_chunk, prep_workers=PLAN_PREP_WORKERS,
     )
     return results  # type: ignore[return-value]
@@ -367,7 +360,6 @@ def run_matrix(
     scenarios: Sequence[Scenario],
     backend: str = "numpy",
     chunk_size: Optional[int] = DEFAULT_CHUNK_SIZE,
-    executor: Optional[str] = None,
     ingest: Optional[str] = None,
 ) -> List[SimResult]:
     """Run every scenario; order of results matches the input order.
@@ -390,7 +382,7 @@ def run_matrix(
         if plan_supported(scenarios):
             return run_plan(
                 build_plan(scenarios), backend=backend_r,
-                chunk_size=chunk_size, executor=executor,
+                chunk_size=chunk_size,
             )
     return run_built(
         [
@@ -402,7 +394,6 @@ def run_matrix(
         backend=backend,
         chunk_size=chunk_size,
         hints=[shape_hint(_effective_cc(sc)) for sc in scenarios],
-        executor=executor,
         fabrics=[sc.shared_fabric for sc in scenarios],
     )
 
@@ -523,11 +514,9 @@ def run_tune(args, scenarios: Sequence[Scenario]) -> int:
         n_candidates=args.candidates,
         history=history,
         chunk_size=args.chunk_size,
-        executor=args.executor,
     )
     heuristics = run_matrix(
         scenarios, backend=args.backend, chunk_size=args.chunk_size,
-        executor=args.executor,
     )
     report = tune.regret_report(scenarios, heuristics, result)
     n_ctx = len(result.tables)
@@ -550,7 +539,7 @@ def run_tune(args, scenarios: Sequence[Scenario]) -> int:
 def _print_wall_breakdown() -> None:
     """The ``--verbose`` prep-vs-compute split: aggregate thread-seconds
     per pipeline phase from the shared wall accumulators (phases overlap
-    under the async executor, so they need not sum to elapsed time), and
+    in the pipelined executor, so they need not sum to elapsed time), and
     the jax backend's device rounds split into upload, device call and
     download inside compute."""
     from .fabric import stats as fabric_stats
@@ -585,12 +574,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument(
         "--chunk-size", type=int, default=DEFAULT_CHUNK_SIZE,
         help="scenarios per batched execution chunk (bounds memory)",
-    )
-    ap.add_argument(
-        "--executor", choices=EXECUTOR_MODES, default=None,
-        help="chunk execution strategy: the overlap-pipelined multi-"
-        "device default ('async') or the historical strictly-serial "
-        "loop ('serial'); also via REPRO_FABRIC_EXECUTOR",
     )
     ap.add_argument("--out", default="tests/golden/eval_matrix.json")
     ap.add_argument("--refresh-golden", action="store_true")
@@ -637,7 +620,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         return rc
     results = run_matrix(
         scenarios, backend=args.backend, chunk_size=args.chunk_size,
-        executor=args.executor,
     )
     if args.verbose:
         _print_wall_breakdown()

@@ -35,9 +35,6 @@ trial group is cloned under a renamed fabric group (``g000.p0k2c5``) so
 clones never couple with each other or the original, and all clones of
 one descent step sweep through ONE :func:`repro.eval.runner.run_matrix`
 call — no per-candidate Python loop.
-
-``benchmarks/mega_sweep.py --matrix tenant-smoke`` embeds the summary in
-the ``tenant_fleet`` row of ``BENCH_eval_matrix.json``.
 """
 from __future__ import annotations
 

@@ -189,7 +189,6 @@ def oracle_search(
     space: Optional[Callable[[Scenario], Sequence]] = None,
     history=None,
     chunk_size: Optional[int] = DEFAULT_CHUNK_SIZE,
-    executor: Optional[str] = None,
 ) -> TuneResult:
     """Exhaustive grid search, executed as one batched sweep.
 
@@ -216,7 +215,6 @@ def oracle_search(
         expanded.extend(rows)
     results = run_matrix(
         expanded, backend=backend, chunk_size=chunk_size,
-        executor=executor,
     )
     tables: Dict[ContextKey, ContextTable] = {}
     for key, lo, hi in spans:

@@ -141,7 +141,6 @@ def _evaluate(
     rows: Sequence[Tuple[_Context, Triple, float]],
     backend: str,
     chunk_size: Optional[int],
-    executor: Optional[str] = None,
 ) -> List[float]:
     """One batched sweep over (context, candidate, fraction) rows ->
     throughputs, input order. Rows carry a capacity shape hint (a static
@@ -166,7 +165,7 @@ def _evaluate(
         hints.append(shape_hint(triple[2]))
     results = run_built(
         builders, names, costs, backend=backend, chunk_size=chunk_size,
-        hints=hints, executor=executor,
+        hints=hints,
     )
     return [r.throughput for r in results]
 
@@ -251,7 +250,6 @@ def successive_halving(
     space: Optional[Callable[[Scenario], Sequence]] = None,
     history=None,
     chunk_size: Optional[int] = DEFAULT_CHUNK_SIZE,
-    executor: Optional[str] = None,
 ) -> TuneResult:
     """Budgeted grid search: shrink the candidate axis between sweeps."""
     if eta < 2:
@@ -297,7 +295,7 @@ def successive_halving(
                     continue
                 rows.append((ctx, cands[key][idx], fraction))
                 row_of.append((key, idx))
-        throughputs = _evaluate(rows, backend, chunk_size, executor)
+        throughputs = _evaluate(rows, backend, chunk_size)
         evals += len(rows)
         for (key, idx), thr in zip(row_of, throughputs):
             scores.setdefault(key, {})[idx] = thr
@@ -362,7 +360,6 @@ def hill_climb(
     space_builder: Optional[Callable[[Scenario], ParamSpace]] = None,
     history=None,
     chunk_size: Optional[int] = DEFAULT_CHUNK_SIZE,
-    executor: Optional[str] = None,
 ) -> TuneResult:
     """Coordinate descent on the log-spaced knob axes.
 
@@ -409,7 +406,7 @@ def hill_climb(
                     rows.append((contexts[key], _triple_of(sp, idx), 1.0))
                     row_of.append((key, idx))
         if rows:
-            throughputs = _evaluate(rows, backend, chunk_size, executor)
+            throughputs = _evaluate(rows, backend, chunk_size)
             evals += len(rows)
             for (key, idx), thr in zip(row_of, throughputs):
                 cache[key][idx] = thr

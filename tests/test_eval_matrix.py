@@ -316,16 +316,12 @@ def test_matrix_results_keep_input_order_across_executors():
     reference = {
         sc.name: build_simulation(sc).run() for sc in scenarios
     }
-    for executor in ("serial", "async"):
-        for chunk_size in (2, 3, 64):
-            out = run_matrix(
-                scenarios, backend="numpy", chunk_size=chunk_size,
-                executor=executor,
-            )
-            assert len(out) == len(scenarios)
-            for sc, r in zip(scenarios, out):
-                ref = reference[sc.name]
-                assert r.total_bytes == ref.total_bytes, sc.name
-                assert r.throughput == pytest.approx(
-                    ref.throughput, rel=1e-9
-                ), (executor, chunk_size, sc.name)
+    for chunk_size in (2, 3, 64):
+        out = run_matrix(scenarios, backend="numpy", chunk_size=chunk_size)
+        assert len(out) == len(scenarios)
+        for sc, r in zip(scenarios, out):
+            ref = reference[sc.name]
+            assert r.total_bytes == ref.total_bytes, sc.name
+            assert r.throughput == pytest.approx(
+                ref.throughput, rel=1e-9
+            ), (chunk_size, sc.name)

@@ -5,14 +5,20 @@ shard layer.
 The invariants under test are the ones ``eval.runner`` promises:
 
   * results come back in **input order**, independent of chunk
-    interleaving, device assignment, executor mode, and donation;
-  * ``REPRO_FABRIC_EXECUTOR=serial`` preserves the historical strictly
-    serial path (and the async pipeline matches it bitwise);
-  * ``SYNC_STATS`` totals are identical whether chunks run serially or
-    interleaved (per-run private accumulation, one locked merge);
+    interleaving and device assignment, and equal to one direct driver
+    call on the same rows (the NumPy driver is the bitwise reference);
+  * the device loop's carry is donated, and donated programs read back
+    from the persistent cache give the reference's results;
+  * ``SYNC_STATS`` totals are identical whether runs go one after
+    another or interleaved (per-run private accumulation, one locked
+    merge);
+  * errors in a chunk's build or run reach the caller and drop the
+    chunks after it;
   * the ``build_files`` byte-bounded LRU survives concurrent access;
-  * AOT-warmed signatures serve runs without a fresh jit trace.
+  * AOT-warmed signatures serve runs without a fresh jit trace, and
+    executor and direct runs share one compiled program per signature.
 """
+import dataclasses
 import os
 import subprocess
 import sys
@@ -32,10 +38,13 @@ from repro.eval.fabric.bucketing import (
     signature_ladder,
 )
 from repro.eval.fabric.driver import FabricSimulation
-from repro.eval.fabric.executor import execute_chunks, executor_mode
+from repro.core.simulator import Simulation
+from repro.eval.fabric.executor import execute_chunks
 from repro.eval.fabric.jax_backend import JaxFabricSimulation
+from repro.eval.fabric.jaxenv import x64
 from repro.eval.runner import run_matrix
 from repro.eval.scenarios import build_simulation, smoke_matrix
+from test_resident_carry import _assert_same_as_numpy
 
 
 def _mixed_batch(n=10):
@@ -56,91 +65,27 @@ def _mixed_batch(n=10):
 
 
 # ------------------------------------------------------------------ #
-# mode resolution + serial escape hatch
+# result ordering + equivalence with one direct driver call
 # ------------------------------------------------------------------ #
 
 
-def test_executor_mode_resolution(monkeypatch):
-    monkeypatch.delenv("REPRO_FABRIC_EXECUTOR", raising=False)
-    assert executor_mode() == "async"
-    assert executor_mode("serial") == "serial"
-    monkeypatch.setenv("REPRO_FABRIC_EXECUTOR", "serial")
-    assert executor_mode() == "serial"
-    assert executor_mode("async") == "async"  # explicit arg wins
-    monkeypatch.setenv("REPRO_FABRIC_EXECUTOR", "bogus")
-    with pytest.raises(ValueError):
-        executor_mode()
-
-
-def test_donation_resolution(monkeypatch):
-    monkeypatch.delenv("REPRO_FABRIC_DONATE", raising=False)
-    monkeypatch.delenv("REPRO_FABRIC_EXECUTOR", raising=False)
-    assert jax_backend.donation_enabled() is True  # async default
-    monkeypatch.setenv("REPRO_FABRIC_EXECUTOR", "serial")
-    assert jax_backend.donation_enabled() is False  # historical path
-    monkeypatch.setenv("REPRO_FABRIC_DONATE", "1")
-    assert jax_backend.donation_enabled() is True  # env overrides
-    monkeypatch.setenv("REPRO_FABRIC_DONATE", "0")
-    monkeypatch.delenv("REPRO_FABRIC_EXECUTOR", raising=False)
-    assert jax_backend.donation_enabled() is False
-    assert jax_backend.donation_enabled(True) is False  # env beats kwarg
-
-
-def test_donation_survives_persistent_cache(monkeypatch, tmp_path):
-    """A configured persistent compilation cache leaves donation exactly
-    as it resolves without one (donated programs are cached like any
-    other); the env override still beats everything both ways."""
-    import jax
-
-    monkeypatch.delenv("REPRO_FABRIC_DONATE", raising=False)
-    monkeypatch.delenv("REPRO_FABRIC_EXECUTOR", raising=False)
-    before = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
-    try:
-        assert jax_backend.donation_enabled() is True  # async default
-        assert jax_backend.donation_enabled(True) is True
-        monkeypatch.setenv("REPRO_FABRIC_EXECUTOR", "serial")
-        assert jax_backend.donation_enabled() is False  # serial undonated
-        monkeypatch.setenv("REPRO_FABRIC_DONATE", "0")
-        monkeypatch.delenv("REPRO_FABRIC_EXECUTOR", raising=False)
-        assert jax_backend.donation_enabled() is False  # kill switch wins
-        monkeypatch.setenv("REPRO_FABRIC_DONATE", "1")
-        monkeypatch.setenv("REPRO_FABRIC_EXECUTOR", "serial")
-        assert jax_backend.donation_enabled() is True  # force wins
-    finally:
-        jax.config.update("jax_compilation_cache_dir", before)
-
-
-def test_serial_env_escape_hatch(monkeypatch):
-    """REPRO_FABRIC_EXECUTOR=serial must route through the plain loop:
-    no prep/compute threads are spawned at all."""
-    monkeypatch.setenv("REPRO_FABRIC_EXECUTOR", "serial")
-    spawned = []
-    orig = threading.Thread
-
-    class SpyThread(orig):
-        def __init__(self, *a, **kw):
-            spawned.append(kw.get("name"))
-            super().__init__(*a, **kw)
-
-    monkeypatch.setattr(threading, "Thread", SpyThread)
-    m = _mixed_batch(6)
-    out = run_matrix(m, backend="numpy", chunk_size=2)
-    assert len(out) == 6 and all(r is not None for r in out)
-    assert not any(n and n.startswith("fabric-") for n in spawned)
-
-
-# ------------------------------------------------------------------ #
-# result ordering + serial/async equivalence
-# ------------------------------------------------------------------ #
+def _direct(cls, scenarios):
+    """One driver call on every row, outside the executor."""
+    return cls(
+        [build_simulation(sc) for sc in scenarios],
+        names=[sc.name for sc in scenarios],
+    ).run()
 
 
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_async_matches_serial_bitwise(backend):
+    """The pipeline over chunks of 4 gives every row the result one
+    direct driver call on all rows gives it."""
     m = _mixed_batch(10)
-    serial = run_matrix(m, backend=backend, chunk_size=4, executor="serial")
-    pipelined = run_matrix(m, backend=backend, chunk_size=4, executor="async")
-    for s, a in zip(serial, pipelined):
+    cls = JaxFabricSimulation if backend == "jax" else FabricSimulation
+    direct = _direct(cls, m)
+    pipelined = run_matrix(m, backend=backend, chunk_size=4)
+    for s, a in zip(direct, pipelined):
         assert a.total_bytes == s.total_bytes
         assert a.total_time == s.total_time
         assert a.n_events == s.n_events
@@ -151,11 +96,9 @@ def test_results_in_input_order_any_chunking():
     """Per-row results are independent of chunk composition and always
     land at the row's input index (scenarios never interact)."""
     m = _mixed_batch(9)
-    baseline = run_matrix(m, backend="numpy", executor="serial")
+    baseline = _direct(FabricSimulation, m)
     for chunk_size in (1, 2, 5, 64):
-        out = run_matrix(
-            m, backend="numpy", chunk_size=chunk_size, executor="async"
-        )
+        out = run_matrix(m, backend="numpy", chunk_size=chunk_size)
         for b, o in zip(baseline, out):
             assert o.total_time == b.total_time
             assert o.total_bytes == b.total_bytes
@@ -168,9 +111,7 @@ def test_execute_chunks_writes_original_indices():
     results = [None] * 6
     # deliberately scrambled, overlapping-free parts
     parts = [[4, 1], [5, 0], [2, 3]]
-    execute_chunks(
-        FabricSimulation, parts, builders, names, results, mode="async"
-    )
+    execute_chunks(FabricSimulation, parts, builders, names, results)
     assert all(r is not None for r in results)
     direct = FabricSimulation(
         [build_simulation(m[1])], names=[m[1].name]
@@ -189,9 +130,99 @@ def test_executor_propagates_builder_errors():
     builders[2] = boom
     with pytest.raises(RuntimeError, match="builder exploded"):
         execute_chunks(
-            FabricSimulation, [[0, 1], [2, 3]], builders, names,
-            [None] * 4, mode="async",
+            FabricSimulation, [[0, 1], [2, 3]], builders, names, [None] * 4,
         )
+
+
+def test_executor_propagates_run_errors_and_drops_later_chunks():
+    """A row past its ``max_time`` raises in the compute worker's
+    ``driver.run()``: the error reaches the caller, and no chunk after
+    the failing one writes a result."""
+    m = _mixed_batch(4)
+
+    def make_chunk(part, dev):
+        sims = []
+        for i in part:
+            base = build_simulation(m[i])
+            sims.append(
+                Simulation(
+                    base.scheduler.chunks, base.network, base.scheduler,
+                    tick_period=m[i].tick_period,
+                    max_time=2.0 if i == 0 else base.max_time,
+                )
+            )
+        return JaxFabricSimulation(
+            sims, names=[m[i].name for i in part], device=dev
+        )
+
+    results = [None] * 4
+    with pytest.raises(RuntimeError, match="exceeded max_time"):
+        execute_chunks(
+            JaxFabricSimulation, [[0], [1], [2], [3]], None, None, results,
+            make_chunk=make_chunk,
+        )
+    assert results == [None] * 4
+
+
+def test_execute_chunks_without_parts_starts_no_thread(monkeypatch):
+    """No parts: ``results`` comes back untouched and no pipeline thread
+    (prep, compute or warm) is started."""
+    spawned = []
+    orig = threading.Thread
+
+    class SpyThread(orig):
+        def __init__(self, *a, **kw):
+            spawned.append(kw.get("name"))
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(threading, "Thread", SpyThread)
+    results = ["kept"]
+    out = execute_chunks(JaxFabricSimulation, [], [], [], results)
+    assert out is results and results == ["kept"]
+    assert spawned == []
+
+
+def test_prep_stays_within_the_staging_bound():
+    """While the first chunk's run blocks, prep builds only what the
+    queue bound admits: the running chunk, ``DEFAULT_QUEUE_DEPTH``
+    staged ones, and one built and waiting for room."""
+    release = threading.Event()
+    built = []
+
+    class _Blocking:
+        def __init__(self, part):
+            self.part = part
+
+        def run(self):
+            if self.part == [0]:
+                assert release.wait(timeout=60)
+            return [f"row{i}" for i in self.part]
+
+    def make_chunk(part, dev):
+        built.append(part[0])
+        return _Blocking(part)
+
+    results = [None] * 8
+    t = threading.Thread(
+        target=execute_chunks,
+        args=(_Blocking, [[i] for i in range(8)], None, None, results),
+        kwargs={"make_chunk": make_chunk},
+    )
+    bound = 2 + executor_mod.DEFAULT_QUEUE_DEPTH
+    t.start()
+    try:
+        for _ in range(200):
+            if len(built) >= bound:
+                break
+            threading.Event().wait(0.01)
+        threading.Event().wait(0.3)  # room for prep to overrun, were it able
+        n_built = len(built)
+    finally:
+        release.set()
+        t.join(timeout=60)
+    assert not t.is_alive()
+    assert n_built == bound
+    assert results == [f"row{i}" for i in range(8)]
 
 
 # ------------------------------------------------------------------ #
@@ -200,15 +231,37 @@ def test_executor_propagates_builder_errors():
 
 
 def test_donation_on_off_identical_results():
+    """The donated device loop gives the NumPy driver's results."""
     m = _mixed_batch(4)
-    sims = lambda: [build_simulation(sc) for sc in m]  # noqa: E731
-    names = [sc.name for sc in m]
-    on = JaxFabricSimulation(sims(), names=names, donate=True).run()
-    off = JaxFabricSimulation(sims(), names=names, donate=False).run()
-    for a, b in zip(on, off):
-        assert a.total_time == b.total_time
-        assert a.total_bytes == b.total_bytes
-        assert a.n_events == b.n_events
+    _assert_same_as_numpy(
+        _direct(JaxFabricSimulation, m), _direct(FabricSimulation, m)
+    )
+
+
+def test_device_round_consumes_the_donated_carry():
+    """One round of ``_device_call`` deletes the state arrays passed in
+    ``mut`` (their buffers now back the output state) and leaves the
+    read-only ``const`` tables and the file sizes alive."""
+    sc = Scenario(
+        network=testbeds.XSEDE.name, dataset="mixed", algorithm="promc",
+        max_cc=4,
+    )
+    drv = JaxFabricSimulation(
+        [build_simulation(sc) for _ in range(3)], names=list("abc")
+    )
+    drv.start()
+    drv._stall = np.zeros(drv.S, dtype=np.int64)
+    drv._q_pad = drv.qsizes.shape[0]
+    with x64():
+        mut, const = drv._upload()
+        qsizes = drv._to_device(drv.qsizes)
+        state, _ = drv._device_call(mut, const, qsizes)
+        state["t"].block_until_ready()
+    for key in ("t", "rem", "done", "busy", "chunk_of", "queue_bytes"):
+        assert mut[key].is_deleted(), key
+        assert not state[key].is_deleted(), key
+    assert not any(v.is_deleted() for v in const.values())
+    assert not qsizes.is_deleted()
 
 
 @pytest.fixture
@@ -279,51 +332,39 @@ def test_arm_compile_cache_placement(monkeypatch, tmp_path):
 
 def test_donated_run_correct_with_cache_dir_configured(fresh_compile_cache):
     """With a persistent compilation cache configured, donated programs
-    are written to it and a donated run matches the undonated one."""
+    are written to it and a donated run matches the NumPy driver."""
     import jax
 
     m = _mixed_batch(4)
-    sims = lambda: [build_simulation(sc) for sc in m]  # noqa: E731
-    names = [sc.name for sc in m]
     jax.clear_caches()
-    on = JaxFabricSimulation(sims(), names=names, donate=True).run()
-    off = JaxFabricSimulation(sims(), names=names, donate=False).run()
+    on = _direct(JaxFabricSimulation, m)
     assert any(
         "device_rounds" in f for f in _cached_files(fresh_compile_cache)
     )
-    for a, b in zip(on, off):
-        assert a.total_time == b.total_time
-        assert a.total_bytes == b.total_bytes
-        assert a.n_events == b.n_events
+    _assert_same_as_numpy(on, _direct(FabricSimulation, m))
 
 
 def test_donated_program_read_back_from_cache(fresh_compile_cache):
     """The stale-alias regression: a donated executable *read back* from
     the persistent cache (in-memory caches cleared, so nothing is
-    recompiled) must give the undonated results, run after run — jax
-    0.4.37 on CPU aliased stale buffers on exactly this path."""
+    recompiled) must give the NumPy driver's results, run after run —
+    jax 0.4.37 on CPU aliased stale buffers on exactly this path."""
     import jax
 
     m = _mixed_batch(6)
-    sims = lambda: [build_simulation(sc) for sc in m]  # noqa: E731
-    names = [sc.name for sc in m]
-    off = JaxFabricSimulation(sims(), names=names, donate=False).run()
+    ref = _direct(FabricSimulation, m)
     jax.clear_caches()
-    JaxFabricSimulation(sims(), names=names, donate=True).run()
+    _direct(JaxFabricSimulation, m)
     written = _cached_files(fresh_compile_cache)
     for _ in range(3):
         jax.clear_caches()
-        on = JaxFabricSimulation(sims(), names=names, donate=True).run()
-        for a, b in zip(on, off):
-            assert (a.total_time, a.total_bytes, a.n_events) == (
-                b.total_time, b.total_bytes, b.n_events
-            )
+        _assert_same_as_numpy(_direct(JaxFabricSimulation, m), ref)
     # the reruns were served from disk: nothing new was compiled
     assert _cached_files(fresh_compile_cache) == written
 
 
 # ------------------------------------------------------------------ #
-# SYNC_STATS: interleaved == serial
+# SYNC_STATS: interleaved == one after another
 # ------------------------------------------------------------------ #
 
 
@@ -366,18 +407,18 @@ def test_sync_stats_interleaved_equals_serial():
 
 
 def test_state_syncs_equal_in_serial_and_async_modes():
-    """The device loop's whole-state syncs count the same under both
-    executors: one per driver run, as no batch here compacts or parks."""
+    """The device loop's whole-state syncs count one per driver run at
+    any chunk size, as no batch here compacts or parks."""
     m = _mixed_batch(10)
     counts = {}
-    for executor in ("serial", "async"):
+    for chunk_size in (4, 2):
         jax_backend.reset_sync_stats()
-        run_matrix(m, backend="jax", chunk_size=4, executor=executor)
+        run_matrix(m, backend="jax", chunk_size=chunk_size)
         stats = jax_backend.SYNC_STATS
-        counts[executor] = (stats["state_syncs"], stats["runs"])
-    assert counts["serial"] == counts["async"]
-    syncs, runs = counts["serial"]
-    assert syncs == runs > 0
+        counts[chunk_size] = (stats["state_syncs"], stats["runs"])
+    for syncs, runs in counts.values():
+        assert syncs == runs > 0
+    assert counts[2][1] > counts[4][1]
 
 
 # ------------------------------------------------------------------ #
@@ -531,21 +572,33 @@ def test_warm_signature_serves_runs_without_fresh_trace():
     sims = [build_simulation(sc) for _ in range(3)]
     probe = JaxFabricSimulation(sims, names=list("abc"))
     sig = canonical_signature(probe)
-    jax_backend.warm_signature(sig, donate=probe.donate)
+    jax_backend.warm_signature(sig)
     # warming twice is a no-op (exactly-once per process)
-    assert jax_backend.warm_signature(sig, donate=probe.donate) is False
-    before = (
-        jax_backend._device_rounds._cache_size()
-        + jax_backend._device_rounds_donated._cache_size()
-    )
+    assert jax_backend.warm_signature(sig) is False
+    before = jax_backend._device_rounds._cache_size()
     out = probe.run()
-    after = (
-        jax_backend._device_rounds._cache_size()
-        + jax_backend._device_rounds_donated._cache_size()
-    )
+    after = jax_backend._device_rounds._cache_size()
     assert after == before  # the AOT executable served the run
     assert out[0].total_bytes > 0
     assert jax_backend.compiled_program_count() >= 1
+
+
+def test_executor_and_direct_runs_share_one_program():
+    """A ``run_matrix`` sweep on one device keys its programs as a direct
+    run does (``device=None``): a direct ``JaxFabricSimulation.run()`` of
+    the same signature afterwards compiles nothing more."""
+    sc = Scenario(
+        network=testbeds.XSEDE.name, dataset="uniform_small",
+        algorithm="mc", max_cc=2, seed=11,
+    )
+    m = [dataclasses.replace(sc, seed=11 + i) for i in range(3)]
+    swept = run_matrix(m, backend="jax")
+    n_programs = jax_backend.compiled_program_count()
+    assert n_programs >= 1
+    direct = _direct(JaxFabricSimulation, m)
+    assert jax_backend.compiled_program_count() == n_programs
+    for a, b in zip(swept, direct):
+        assert (a.total_time, a.total_bytes) == (b.total_time, b.total_bytes)
 
 
 # ------------------------------------------------------------------ #
@@ -559,7 +612,7 @@ from repro.eval.runner import run_matrix
 from repro.eval.scenarios import smoke_matrix
 m = smoke_matrix()[:8]
 ev = run_matrix(m, backend="event")
-ax = run_matrix(m, backend="jax", chunk_size=2, executor="async")
+ax = run_matrix(m, backend="jax", chunk_size=2)
 for e, a in zip(ev, ax):
     assert a.total_bytes == e.total_bytes
     rel = abs(a.throughput - e.throughput) / max(e.throughput, 1e-12)

@@ -44,27 +44,28 @@ def _batch():
     ]
 
 
-def _run_serial_chunk():
-    """One serial chunk through the executor, as ``run_matrix`` does."""
+def _run_one_chunk():
+    """One chunk through the executor's pipeline, as ``run_matrix``
+    runs it."""
     batch = _batch()
     results = [None] * len(batch)
     execute_chunks(
         JaxFabricSimulation, [list(range(len(batch)))],
         [lambda sc=sc: build_simulation(sc) for sc in batch],
-        [sc.name for sc in batch], results, mode="serial",
+        [sc.name for sc in batch], results,
     )
     assert all(r is not None for r in results)
 
 
 def test_jax_round_spans_and_iterations_are_filled():
     stats.reset_sync_stats()
-    _run_serial_chunk()
+    _run_one_chunk()
     s = dict(stats.SYNC_STATS)
     for key in ("upload_wall_s", "device_wall_s", "download_wall_s",
                 "iterations"):
         assert s[key] > 0, (key, s)
     assert s["iterations"] >= s["rounds"] > 0
-    # a single serial chunk: the round spans lie inside its fabric.run
+    # a single chunk: the round spans lie inside its fabric.run
     rounds = s["upload_wall_s"] + s["device_wall_s"] + s["download_wall_s"]
     assert rounds <= s["compute_wall_s"]
 
@@ -116,10 +117,10 @@ def test_profiler_trace_nests_round_spans_in_run(tmp_path):
         sys.path.insert(0, REPO)
     from bench import trace
 
-    _run_serial_chunk()  # compile outside the trace
+    _run_one_chunk()  # compile outside the trace
     jax.profiler.start_trace(str(tmp_path))
     try:
-        _run_serial_chunk()
+        _run_one_chunk()
     finally:
         jax.profiler.stop_trace()
     host = [lines for name, lines in trace.load(str(tmp_path))

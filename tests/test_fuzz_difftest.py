@@ -200,10 +200,7 @@ def test_fuzz_async_executor_matches_event(
     names = [f"v{v}" for v in range(n_variants)]
     ev = [b().run() for b in builders]
     for backend in ("numpy", "jax"):
-        out = run_built(
-            builders, names, backend=backend, chunk_size=2,
-            executor="async",
-        )
+        out = run_built(builders, names, backend=backend, chunk_size=2)
         for i, (e, r) in enumerate(zip(ev, out)):
             assert r.total_bytes == e.total_bytes, i
             rel = abs(r.throughput - e.throughput) / max(

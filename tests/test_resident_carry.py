@@ -5,7 +5,7 @@ row's replay, a compaction, an error report or the end of a run.
 
 A small ``_ROUND_CAP`` makes every batch here take many rounds, so most
 rounds resume from the device-resident state. The loop programs are
-traced with the cap inlined, so the jit twins and the AOT cache are
+traced with the cap inlined, so the jitted loops and the AOT cache are
 cleared on both sides of each test.
 """
 import dataclasses
@@ -25,12 +25,7 @@ from repro.eval.scenarios import build_simulation
 #: needs several rounds
 SMALL_CAP = 8
 
-_LOOP_FNS = (
-    jax_backend._device_rounds,
-    jax_backend._device_rounds_donated,
-    jax_backend._device_rounds_coupled,
-    jax_backend._device_rounds_coupled_donated,
-)
+_LOOP_FNS = (jax_backend._device_rounds, jax_backend._device_rounds_coupled)
 
 
 def _clear_loop_programs():
@@ -88,21 +83,20 @@ def _assert_same_as_numpy(got, ref):
             assert g_bytes[k] == pytest.approx(r_bytes[k], rel=1e-14), k
 
 
-@pytest.mark.parametrize("donate", [False, True], ids=["kept", "donated"])
-def test_resident_rounds_match_one_round_and_numpy(donate, monkeypatch):
+def test_resident_rounds_match_one_round_and_numpy(monkeypatch):
     """(a) Built-in schedulers, no compaction: many resident rounds give
     results bit-identical to the same batch run in one device round, and
     equal to the NumPy driver's; the state is synced once, at the end."""
     batch = _mixed(6)
     make = lambda: [build_simulation(sc) for sc in batch]  # noqa: E731
     ref, _ = _run(FabricSimulation, make)
-    one, one_stats = _run(JaxFabricSimulation, make, donate=donate)
+    one, one_stats = _run(JaxFabricSimulation, make)
     assert one_stats["rounds"] == 1
 
     _clear_loop_programs()
     monkeypatch.setattr(jax_backend, "_ROUND_CAP", SMALL_CAP)
     try:
-        got, stats = _run(JaxFabricSimulation, make, donate=donate)
+        got, stats = _run(JaxFabricSimulation, make)
     finally:
         _clear_loop_programs()
     assert stats["rounds"] > 1

@@ -214,6 +214,28 @@ def test_tenant_matrix_smoke_difftest_zero_replays():
     assert stats["replay_rounds"] == 0
 
 
+def test_coupled_jax_batch_matches_numpy_driver():
+    """A coupled batch (two tenant groups on shared links) run by the
+    jax driver's coupled loop gives the NumPy driver's results on the
+    same rows: every field but the per-chunk byte totals exactly."""
+    from test_resident_carry import _assert_same_as_numpy
+
+    from repro.eval.fabric.jax_backend import JaxFabricSimulation
+
+    scenarios = tenant_matrix(n_groups=2)
+
+    def run(cls):
+        drv = cls(
+            [build_simulation(sc) for sc in scenarios],
+            names=[sc.name for sc in scenarios],
+            fabric=[sc.shared_fabric for sc in scenarios],
+        )
+        assert drv.coupled
+        return drv.run()
+
+    _assert_same_as_numpy(run(JaxFabricSimulation), run(FabricSimulation))
+
+
 def test_plan_and_legacy_ingest_agree_on_coupled_rows():
     """Columnar plan ingest and the legacy per-row object chain must
     produce identical coupled results (the plan carries the fabric

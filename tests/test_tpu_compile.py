@@ -11,7 +11,7 @@ the chip. Each compile takes tens of seconds, so the file holds two:
   * ``_device_rounds_coupled`` at the tenant-smoke fleet's signature
     (6 groups, 29 coupled rows).
 
-Both are the donated twins the async executor runs.
+Both are the donated programs every run calls.
 """
 import os
 
@@ -83,7 +83,7 @@ def test_grid_loop_compiles_for_v5e(topo, no_compile_cache):
         mut, const, qsizes = jax_backend.signature_shapes(
             GRID_SIG, topo.devices[0]
         )
-        compiled = jax_backend._device_rounds_donated.lower(
+        compiled = jax_backend._device_rounds.lower(
             mut, const, qsizes, COMPACT_FLOOR
         ).compile()
     assert 0 < _device_bytes(compiled) < MAX_DEVICE_BYTES
@@ -112,7 +112,7 @@ def test_fleet_loop_compiles_for_v5e(topo, one_chip, no_compile_cache):
             "link_cap": aval((FLEET_LINKS,), jnp.float64),
             "gslot": aval((FLEET_GROUPS,), jnp.float64),
         }
-        compiled = jax_backend._device_rounds_coupled_donated.lower(
+        compiled = jax_backend._device_rounds_coupled.lower(
             mut, const, qsizes, fab, COMPACT_FLOOR
         ).compile()
     assert 0 < _device_bytes(compiled) < MAX_DEVICE_BYTES
